@@ -129,7 +129,7 @@ def kernels_refine_autotune() -> List[dict]:
             derived="default-knob search over the bench batch"),
         row("kernels/refine/autotune/winner", entry.median_ms * 1e-3,
             derived=(f"round_leaves={cfg.round_leaves} "
-                     f"dma_depth={cfg.dma_depth} block_q={cfg.block_q} "
+                     f"dma_depth={cfg.dma_depth} "
                      f"pq_budget={cfg.pq_budget}"),
             speedup=round(entry.baseline_ms
                           / max(entry.median_ms, 1e-9), 3),

@@ -53,6 +53,9 @@ def main() -> None:
                          "asserted kernels/refine/roofline_frac row)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import fresh_bench
     from . import roofline_table
     from .common import fmt_row

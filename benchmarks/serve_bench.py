@@ -12,13 +12,14 @@ Two legs share one Poisson driver:
 
 * local   — the engine over an unsharded index (in-process);
 * sharded — the SAME stream through an engine over `index.shard(mesh)`
-  on a forced 2-device host CPU mesh.  jax pins the device count at
-  first init, so this leg runs in a SUBPROCESS (`python -m
+  (`serve/sharded/warmup_aot_compile`, `serve/sharded/poisson/steady`).
+  On an accelerator it runs in-process over the real devices.  On the
+  CPU it runs on a forced 2-device host mesh: jax pins the device count
+  at first init, so there the leg runs in a SUBPROCESS (`python -m
   benchmarks.serve_bench --sharded-child`) with
   XLA_FLAGS=--xla_force_host_platform_device_count=2 and hands its rows
-  back as JSON on stdout (`serve/sharded/warmup_aot_compile`,
-  `serve/sharded/poisson/steady`).  Read EXPERIMENTS.md §Serving for
-  why sharded CPU QPS is a property check, not a speedup claim.
+  back as JSON on stdout.  Read EXPERIMENTS.md §Serving for why sharded
+  CPU QPS is a property check, not a speedup claim.
 
 Open-loop means arrivals do NOT wait for completions (the classic
 coordinated-omission trap): submission times are scheduled ahead from an
@@ -126,9 +127,9 @@ def serve_poisson() -> List[dict]:
         eng.close()
 
 
-def _sharded_child() -> None:
-    """Body of the forced-2-device subprocess: sharded engine over the
-    same workload; prints rows as one marked JSON line."""
+def _sharded_rows() -> List[dict]:
+    """The sharded leg: an engine over the same workload, sharded over
+    every device this process sees."""
     import jax
     walks = random_walk(N_SERIES, 256, seed=41)
     queries = query_workload(walks, 64, noise_sigma=0.05, seed=42)
@@ -140,17 +141,27 @@ def _sharded_child() -> None:
                                     linger_ms=1.0, warm_ks=(K,),
                                     sync_every=2))
     try:
-        rows = _drive_poisson(eng, queries, "serve/sharded",
+        return _drive_poisson(eng, queries, "serve/sharded",
                               extra_derived=f"mesh=data:{n_dev}")
     finally:
         eng.close()
-    print(_CHILD_MARK + json.dumps(rows), flush=True)
+
+
+def _sharded_child() -> None:
+    """Body of the forced-2-device subprocess: prints the sharded rows
+    as one marked JSON line."""
+    print(_CHILD_MARK + json.dumps(_sharded_rows()), flush=True)
 
 
 def serve_sharded() -> List[dict]:
-    """Spawn the sharded leg under a forced multi-device host platform
-    (the parent process keeps its single device — jax pins the count at
-    first init) and adopt its rows."""
+    """The sharded leg.  On an accelerator it runs in this process, on
+    the real devices: this process already holds them, and a child that
+    needs them would fail or hang.  On the CPU it spawns a child under a
+    forced multi-device host platform (this process keeps its single
+    device — jax pins the count at first init) and adopts its rows."""
+    import jax
+    if jax.default_backend() != "cpu":
+        return _sharded_rows()
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{SHARDED_DEVICES}")

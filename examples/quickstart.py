@@ -18,7 +18,9 @@ import numpy as np
 from repro.api import FreshIndex, IndexConfig
 from repro.core import search_bruteforce
 from repro.data.synthetic import query_workload, random_walk
+from repro.launch.compile_cache import use_compile_cache
 
+use_compile_cache()
 N, L, Q, K = 100_000, 256, 100, 10
 
 print(f"generating {N} random-walk series of length {L} ...")

@@ -19,7 +19,9 @@ import numpy as np
 from repro.api import FreshIndex, IndexConfig
 from repro.serve import (AdmissionError, DeadlineExceeded, EngineConfig)
 from repro.data.synthetic import query_workload, random_walk
+from repro.launch.compile_cache import use_compile_cache
 
+use_compile_cache()
 N, L, K = 20_000, 256, 10
 
 print(f"building a FreSh index over {N} series ...")

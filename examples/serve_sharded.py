@@ -33,8 +33,10 @@ import jax.numpy as jnp
 from repro.api import FreshIndex, IndexConfig
 from repro.core.refresh import WorkerCrash
 from repro.data.synthetic import query_workload, random_walk
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import EngineConfig
 
+use_compile_cache()
 N, L, K = 8_000, 256, 10
 
 n_dev = len(jax.devices())

@@ -92,7 +92,6 @@ class StubConfig:
     backend = "ref"
     pq_budget = None
     dma_depth = None
-    block_q = None
 
 
 class _StubCore:

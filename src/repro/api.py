@@ -91,6 +91,7 @@ import dataclasses
 import time
 from typing import Iterable, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -137,8 +138,6 @@ class IndexConfig:
     dma_depth      Mosaic refine-kernel HBM->VMEM DMA ring depth (pallas
                    backend only; 1 = pipelined BlockSpec kernel, >= 2 =
                    explicit multi-buffered ring); None = autotune/default
-    block_q        Triton refine-kernel query rows per program (pallas
-                   backend only); None = autotune/default
 
     Unset (None) knobs resolve per `FreshIndex.search_knobs`: a fresh
     `kernels.autotune.AutotuneTable` entry for this device/shape when
@@ -155,7 +154,6 @@ class IndexConfig:
     round_leaves: Optional[int] = None
     pq_budget: Optional[int] = None
     dma_depth: Optional[int] = None
-    block_q: Optional[int] = None
 
     def __post_init__(self):
         if self.bound not in _BOUNDS:
@@ -177,8 +175,6 @@ class IndexConfig:
             raise ValueError("pq_budget must be >= 1 or None")
         if self.dma_depth is not None and self.dma_depth < 1:
             raise ValueError("dma_depth must be >= 1 or None")
-        if self.block_q is not None and self.block_q < 1:
-            raise ValueError("block_q must be >= 1 or None")
 
     def validate_series_len(self, L: int) -> None:
         """Raise ValueError unless series length L divides into
@@ -253,7 +249,9 @@ class FreshIndex:
         """Bulk-build an index over `data`, an (n, L) float array.
 
         Args:
-            data: (n, L) series matrix; n == 0 is the legal bootstrap.
+            data: (n, L) series matrix (host or device array; a device
+                array is built in place, never copied through the host);
+                n == 0 is the legal bootstrap.
             config: IndexConfig (None = defaults).
             **overrides: IndexConfig fields, so the two spellings
                 `build(x, IndexConfig(leaf_capacity=32))` and
@@ -279,11 +277,12 @@ class FreshIndex:
         cfg = config or IndexConfig()
         if overrides:
             cfg = dataclasses.replace(cfg, **overrides)
-        data = np.asarray(data)
+        if not isinstance(data, jax.Array):
+            data = np.asarray(data)     # a device array stays where it is
         if data.ndim != 2:
             raise ValueError(f"data must be (n, L), got shape {data.shape}")
         if data.shape[0] == 0:
-            return cls.builder(cfg).feed(data).finalize()
+            return cls.builder(cfg).feed(np.asarray(data)).finalize()
         cfg.validate_series_len(data.shape[1])
         idx = build_index(jnp.asarray(data), segments=cfg.segments,
                           bits=cfg.bits, leaf_capacity=cfg.leaf_capacity,
@@ -459,14 +458,14 @@ class FreshIndex:
         rl = round_leaves if round_leaves is not None else kn.round_leaves
         pqb = pq_budget if pq_budget is not None else kn.pq_budget
         bk = backend if backend is not None else self.config.backend
-        dd, bq = (kn.dma_depth, kn.block_q) if bk == "pallas" else (1, 1)
+        dd = kn.dma_depth if bk == "pallas" else 1
         core, delta, alive, id0 = self.search_view()
         if self._mesh is not None:
             # the mesh placement is part of the key (not just cleared on
             # shard()): a compiled shard_map search can never be replayed
             # against arrays living on a different placement
             key = (k, rl, sync_every, max_rounds, pqb,
-                   bk, dd, bq, rule, mesh_sig(self._mesh))
+                   bk, dd, rule, mesh_sig(self._mesh))
             fn = self._sharded_fns.get(key)
             if fn is None:
                 fn = build_sharded_search(
@@ -474,15 +473,14 @@ class FreshIndex:
                     round_leaves=rl, sync_every=sync_every,
                     max_rounds=max_rounds, znorm=self.config.znorm,
                     pq_budget=pqb, backend=bk,
-                    dma_depth=dd, block_q=bq,
-                    config=self.config, **rule.lower())
+                    dma_depth=dd, config=self.config, **rule.lower())
                 self._sharded_fns[key] = fn
             d, i = fn(core, q)
         else:
             d, i = run_search(core, q, k=k, round_leaves=rl,
                               znorm=self.config.znorm,
                               max_rounds=max_rounds, pq_budget=pqb,
-                              backend=bk, dma_depth=dd, block_q=bq,
+                              backend=bk, dma_depth=dd,
                               config=self.config,
                               **rule.lower())
         if delta is not None:

@@ -78,10 +78,11 @@ def _summarize_block_pallas(raw, *, segments: int, bits: int, znorm: bool):
     """One summarize part through the fused Pallas kernel."""
     from repro.kernels import ops
     x = jnp.asarray(raw)
+    # normalized in the kernel, exactly like the fused build_index
+    p, w = ops.summarize(x, segments=segments, bits=bits, znorm=znorm)
+    w = w.astype(jnp.uint8 if bits <= 8 else jnp.int32)
     x = isax.znormalize(x) if znorm else x
     x = x.astype(jnp.float32)
-    p, w = ops.summarize(x, segments=segments, bits=bits, znorm=False)
-    w = w.astype(jnp.uint8 if bits <= 8 else jnp.int32)
     return x, p, w, jnp.sum(x * x, axis=-1)
 
 

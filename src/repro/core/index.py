@@ -137,20 +137,24 @@ def build_index(raw: jnp.ndarray,
     paths produce bit-identical indexes.
     """
     n, L = raw.shape
-    x = isax.znormalize(raw) if znorm else raw
-    x = x.astype(jnp.float32)
+
+    def prepare(r):
+        r = isax.znormalize(r) if znorm else r
+        return r.astype(jnp.float32)
+
     if backend == "pallas":
         from repro.kernels import ops
-        p, w = ops.summarize(x, segments=segments, bits=bits, znorm=False)
+        # the kernel normalizes in VMEM: no normalized copy hits HBM
+        p, w = ops.summarize(raw, segments=segments, bits=bits, znorm=znorm)
         w = w.astype(jnp.uint8 if bits <= 8 else jnp.int32)
     else:
-        p, w = isax.summarize(x, segments, bits)
+        p, w = isax.summarize(prepare(raw), segments, bits)
 
     # ---- sort by interleaved key (leaf order of the round-robin tree) ----
-    key = isax.interleaved_key(w, bits)                    # (n, lanes)
-    lanes = [key[:, i] for i in range(key.shape[1])]
-    perm = jnp.lexsort(tuple(reversed(lanes)))             # primary lane last
-    x, p, w = x[perm], p[perm], w[perm]
+    perm = isax.lexsort_lanes(isax.interleaved_key(w, bits))
+    # normalize AFTER the gather (row-wise, so the same values): the
+    # gather and the normalization fuse into one pass writing the output
+    x, p, w = prepare(raw[perm]), p[perm], w[perm]
 
     # ---- pad to a whole number of leaves ---------------------------------
     n_pad = -(-n // leaf_capacity) * leaf_capacity

@@ -164,22 +164,40 @@ def interleaved_key(words: jnp.ndarray, bits: int = SAX_BITS) -> jnp.ndarray:
     for b in range(bits - 1, -1, -1):  # MSB plane first
         for s in range(w):
             bitpos.append((s, b))
-    # bit i (0 = most significant) of the key comes from segment s, bit b.
-    planes = []
-    for (s, b) in bitpos:
-        planes.append(((words[..., s] >> b) & 1).astype(jnp.uint32))
-    planes = jnp.stack(planes, axis=-1)  # (..., total) in {0,1}
-    # pack into ceil(total/31) int32 lanes (31 bits per lane keeps sign bit 0;
-    # int64 is unavailable without jax_enable_x64, which we must not force
-    # globally since the model stack runs bf16/f32)
+    # bit i (0 = most significant) of the key comes from segment s, bit b;
+    # pack into ceil(total/31) int32 lanes (31 bits per lane keeps sign bit
+    # 0; int64 is unavailable without jax_enable_x64).  Each lane is built
+    # by shift-and-or on (...,) arrays, never as a (..., total) bit matrix
+    wi = words.astype(jnp.int32)
     lanes = []
     for lane_start in range(0, total, 31):
-        chunk = planes[..., lane_start:lane_start + 31]
-        width = chunk.shape[-1]
-        weights = (jnp.asarray(1, dtype=jnp.int32) <<
-                   jnp.arange(width - 1, -1, -1, dtype=jnp.int32))
-        lanes.append(jnp.sum(chunk.astype(jnp.int32) * weights, axis=-1))
+        chunk = bitpos[lane_start:lane_start + 31]
+        acc = jnp.zeros(words.shape[:-1], jnp.int32)
+        for pos, (s, b) in enumerate(chunk):
+            bit = (wi[..., s] >> b) & 1
+            acc = acc | (bit << (len(chunk) - 1 - pos))
+        lanes.append(acc)
     return jnp.stack(lanes, axis=-1)  # (..., n_lanes)
+
+
+def lexsort_lanes(key: jnp.ndarray) -> jnp.ndarray:
+    """Stable ascending order of (n, n_lanes) keys, lane 0 primary — the
+    device twin of `lexsort_keys` (ties keep their input order).
+
+    One stable single-key sort per lane, least significant lane first
+    (an LSD radix sort over lanes) inside a fori_loop: the TPU compiler
+    takes minutes on one sort with n_lanes keys, and seconds on this."""
+    n, n_lanes = key.shape
+    lanes = key.T                                       # (n_lanes, n)
+    iota = jnp.arange(n, dtype=jnp.int32)
+
+    def one_lane(j, perm):
+        k = jax.lax.dynamic_index_in_dim(lanes, n_lanes - 1 - j,
+                                         keepdims=False)[perm]
+        _, pos = jax.lax.sort((k, iota), num_keys=1, is_stable=True)
+        return perm[pos]
+
+    return jax.lax.fori_loop(0, n_lanes, one_lane, iota)
 
 
 def interleaved_key_np(words: np.ndarray, bits: int = SAX_BITS) -> np.ndarray:

@@ -168,6 +168,65 @@ def _pq_order(lb: jnp.ndarray, K: int, n_rounds_cap: int,
     return order, sorted_lb
 
 
+#: Queries are prepared (z-normalized, PAA, squared norm) and their
+#: winners' distances recomputed in fixed tiles of this many rows.  XLA
+#: on TPU picks a reduction's strategy from the whole array's shape: one
+#: query normalized alone, in a batch of 16 and in a batch of 128 came
+#: out with different last bits.  In fixed tiles a query's answer does
+#: not depend on the batch it rode in, so an engine bucket returns the
+#: facade's bits.
+QUERY_TILE = 128
+
+
+def per_query_tiles(fn, *rows):
+    """`fn(*tiles)` over the leading (query) axis of `rows`, in tiles of
+    QUERY_TILE rows (zero-padded; the pad rows' outputs are dropped).
+    Every tile runs the same program between optimization barriers, so
+    neither the batch size nor the surrounding program can change how
+    one row is computed.  At least one pad row is always added: with
+    none (Q a multiple of QUERY_TILE) the tiles were the caller's array
+    itself, and on TPU that program came out one ulp away from the
+    padded one."""
+    Q = rows[0].shape[0]
+    T = Q // QUERY_TILE + 1
+    pad = T * QUERY_TILE - Q
+    tiles = tuple(
+        jnp.pad(r, ((0, pad),) + ((0, 0),) * (r.ndim - 1))
+        .reshape((T, QUERY_TILE) + r.shape[1:]) for r in rows)
+
+    def one(tile):
+        return jax.lax.optimization_barrier(
+            fn(*jax.lax.optimization_barrier(tile)))
+
+    out = jax.lax.map(one, tiles)
+    return jax.tree.map(
+        lambda o: o.reshape((T * QUERY_TILE,) + o.shape[2:])[:Q], out)
+
+
+def prepare_query_rows(queries: jnp.ndarray, znorm: bool = True,
+                       segments: Optional[int] = None,
+                       index: Optional[FlatIndex] = None):
+    """`prepare_queries` plus each query's squared norm: (q, q_paa,
+    q_sq), computed row by row in QUERY_TILE tiles (`per_query_tiles`)."""
+    if index is not None:
+        segments = index.paa.shape[1]
+    if segments is None:
+        segments = isax.SEGMENTS
+    L = queries.shape[-1]
+    if L % segments != 0:
+        raise ValueError(
+            f"query length {L} is not divisible by the index segment count "
+            f"{segments}; queries must have the same length as the indexed "
+            f"series (pad the feature dim up to a segment multiple)")
+
+    def prep(x):
+        q = isax.znormalize(x) if znorm else x
+        q = q.astype(jnp.float32)
+        return q, isax.paa(q, segments), jnp.sum(q * q, axis=-1)
+
+    return per_query_tiles(prep, queries)
+
+
 def prepare_queries(queries: jnp.ndarray, znorm: bool = True,
                     segments: Optional[int] = None,
                     index: Optional[FlatIndex] = None):
@@ -182,19 +241,19 @@ def prepare_queries(queries: jnp.ndarray, znorm: bool = True,
     behaviour silently fell back to `segments = L`, producing PAA widths
     that disagree with the index).
     """
-    if index is not None:
-        segments = index.paa.shape[1]
-    if segments is None:
-        segments = isax.SEGMENTS
-    L = queries.shape[-1]
-    if L % segments != 0:
-        raise ValueError(
-            f"query length {L} is not divisible by the index segment count "
-            f"{segments}; queries must have the same length as the indexed "
-            f"series (pad the feature dim up to a segment multiple)")
-    q = isax.znormalize(queries) if znorm else queries
-    q = q.astype(jnp.float32)
-    return q, isax.paa(q, segments)
+    q, q_paa, _ = prepare_query_rows(queries, znorm, segments, index)
+    return q, q_paa
+
+
+def direct_sq(q: jnp.ndarray, series: jnp.ndarray,
+              entries: jnp.ndarray) -> jnp.ndarray:
+    """Squared distances of (Q, L) queries to their (Q, k) `entries` of
+    `series`, in direct form (sum of squared differences; the matmul form
+    loses ~1e-3 absolute to f32 cancellation), in QUERY_TILE tiles."""
+    return per_query_tiles(
+        lambda qt, et: jnp.sum(jnp.square(qt[:, None, :] - series[et]),
+                               axis=-1),
+        q, entries)
 
 
 def leaf_lower_bounds(idx: FlatIndex, q_paa: jnp.ndarray,
@@ -216,7 +275,7 @@ def leaf_lower_bounds(idx: FlatIndex, q_paa: jnp.ndarray,
 
 def _refine_round(q, q_sq, series, sq_norms, ids, alive, bsf_d, bsf_e,
                   *, M: int, k: int, backend: str,
-                  dma_depth: int = 1, block_q: int = 1):
+                  dma_depth: int = 1):
     """One refinement round: distances of the addressed leaves' members,
     pruned by `alive`, folded into the (Q, k) BSF buffer.
 
@@ -228,16 +287,16 @@ def _refine_round(q, q_sq, series, sq_norms, ids, alive, bsf_d, bsf_e,
     slots carry lb=BIG and fail `alive`), so the buffer stays
     duplicate-free.
 
-    `dma_depth` / `block_q` are pallas-only kernel-structure knobs
-    (kernels.refine; normally resolved through the autotune table) — the
-    ref backend ignores them, and callers normalize them to the defaults
-    there so they never split its compile cache.
+    `dma_depth` is a pallas-only kernel-structure knob (kernels.refine;
+    normally resolved through the autotune table) — the ref backend
+    ignores it, and callers normalize it to the default there so it
+    never splits its compile cache.
     """
     from repro.kernels import ops, ref
     if backend == "pallas":
         return ops.refine_topk(q, q_sq, series, sq_norms, ids, alive,
                                bsf_d, bsf_e, leaf_capacity=M, k=k,
-                               dma_depth=dma_depth, block_q=block_q)
+                               dma_depth=dma_depth)
     return ref.refine_topk_ref(q, q_sq, series, sq_norms, ids, alive,
                                bsf_d, bsf_e, leaf_capacity=M, k=k)
 
@@ -248,7 +307,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
                      pq_budget: Optional[int] = None,
                      stop_eps: float = 0.0,
                      stop_leaves: Optional[int] = None,
-                     dma_depth: int = 1, block_q: int = 1
+                     dma_depth: int = 1
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The PURE search plan: exact k-NN with every knob fully resolved.
 
@@ -283,11 +342,11 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     guards below emit the unscaled expressions — so exact mode stays
     bit-identical to the seed oracle.
 
-    `dma_depth` / `block_q` pick the pallas refine-kernel structure
-    (kernels.refine: explicit DMA-ring depth on Mosaic, queries per
-    program on Triton) — autotune-resolved knobs that change HOW the
-    round executes, never WHAT it returns.  The ref backend ignores
-    them (callers normalize to 1/1 there).
+    `dma_depth` picks the pallas refine-kernel structure
+    (kernels.refine: pipelined kernel at 1, explicit DMA ring above) —
+    an autotune-resolved knob that changes HOW the round executes, never
+    WHAT it returns.  The ref backend ignores it (callers normalize it
+    to 1 there).
     """
     if backend not in _BACKENDS:
         raise ValueError(f"backend must be one of {_BACKENDS}, "
@@ -299,8 +358,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     M = idx.leaf_capacity
     n_leaves = idx.n_leaves
 
-    q, q_paa = prepare_queries(queries, znorm, index=idx)
-    q_sq = jnp.sum(q * q, axis=-1)
+    q, q_paa, q_sq = prepare_query_rows(queries, znorm, index=idx)
 
     lb = leaf_lower_bounds(idx, q_paa, L, backend)     # (Q, n_leaves)
 
@@ -328,7 +386,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
         bsf_d, bsf_e = _refine_round(q, q_sq, idx.series, idx.sq_norms,
                                      ids, alive, bsf_d, bsf_e,
                                      M=M, k=k, backend=backend,
-                                     dma_depth=dma_depth, block_q=block_q)
+                                     dma_depth=dma_depth)
         return cursor + K, bsf_d, bsf_e
 
     state = (jnp.int32(0), jnp.full((Q, k), BIG),
@@ -341,8 +399,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     # re-sort the buffer by the exact values.
     found = bsf_d < BIG                                  # (Q, k)
     ids = jnp.where(found, idx.perm[bsf_e], -1)
-    d_exact = jnp.sum(jnp.square(q[:, None, :] - idx.series[bsf_e]), axis=-1)
-    d = jnp.where(found, d_exact, bsf_d)
+    d = jnp.where(found, direct_sq(q, idx.series, bsf_e), bsf_d)
     resort = jnp.argsort(d, axis=1)
     d = jnp.sqrt(jnp.take_along_axis(d, resort, axis=1))
     ids = jnp.take_along_axis(ids, resort, axis=1)
@@ -352,8 +409,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
 search_plan = functools.partial(
     jax.jit, static_argnames=("k", "round_leaves", "znorm", "max_rounds",
                               "backend", "pq_budget", "stop_eps",
-                              "stop_leaves", "dma_depth",
-                              "block_q"))(search_plan_impl)
+                              "stop_leaves", "dma_depth"))(search_plan_impl)
 search_plan.__doc__ = search_plan_impl.__doc__
 
 
@@ -373,15 +429,14 @@ def _bruteforce_topk(raw: jnp.ndarray, queries: jnp.ndarray,
     the index search's not-found slots."""
     x = isax.znormalize(raw).astype(jnp.float32) if znorm \
         else raw.astype(jnp.float32)
-    q = isax.znormalize(queries).astype(jnp.float32) if znorm \
-        else queries.astype(jnp.float32)
-    d2 = (jnp.sum(q * q, -1)[:, None] + jnp.sum(x * x, -1)[None, :]
-          - 2.0 * q @ x.T)
+    q, _, q_sq = prepare_query_rows(queries, znorm, segments=1)
+    d2 = (q_sq[:, None] + jnp.sum(x * x, -1)[None, :]
+          - 2.0 * jnp.dot(q, x.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
     if alive is not None:
         d2 = jnp.where(alive[None, :], d2, BIG)
     _, i = jax.lax.top_k(-d2, k)                        # (Q, k)
-    d_exact = jnp.sum(jnp.square(q[:, None, :] - x[i]), axis=-1)
+    d_exact = direct_sq(q, x, i)
     if alive is not None:
         d_exact = jnp.where(alive[i], d_exact, BIG)
     resort = jnp.argsort(d_exact, axis=1)               # see search(): exact
@@ -423,7 +478,7 @@ def snapshot_search_impl(idx: FlatIndex, delta: jnp.ndarray,
                          pq_budget: Optional[int] = None,
                          stop_eps: float = 0.0,
                          stop_leaves: Optional[int] = None,
-                         dma_depth: int = 1, block_q: int = 1
+                         dma_depth: int = 1
                          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Search plan over a (core index, delta buffer) epoch snapshot.
 
@@ -450,7 +505,7 @@ def snapshot_search_impl(idx: FlatIndex, delta: jnp.ndarray,
         idx, queries, k=k, round_leaves=round_leaves, znorm=znorm,
         max_rounds=max_rounds, backend=backend, pq_budget=pq_budget,
         stop_eps=stop_eps, stop_leaves=stop_leaves,
-        dma_depth=dma_depth, block_q=block_q)
+        dma_depth=dma_depth)
     kd = min(k, delta.shape[0])
     dd, di = _bruteforce_topk(delta, queries, k=kd, znorm=znorm,
                               alive=delta_alive)
@@ -462,8 +517,8 @@ def snapshot_search_impl(idx: FlatIndex, delta: jnp.ndarray,
 snapshot_search = functools.partial(
     jax.jit, static_argnames=("k", "n_base", "round_leaves", "znorm",
                               "max_rounds", "backend", "pq_budget",
-                              "stop_eps", "stop_leaves", "dma_depth",
-                              "block_q"))(snapshot_search_impl)
+                              "stop_eps", "stop_leaves",
+                              "dma_depth"))(snapshot_search_impl)
 snapshot_search.__doc__ = snapshot_search_impl.__doc__
 
 
@@ -499,16 +554,15 @@ def run_search(idx: FlatIndex, queries: jnp.ndarray, *,
                pq_budget: Optional[int] = None,
                stop_eps: float = 0.0, stop_leaves: Optional[int] = None,
                dma_depth: Optional[int] = None,
-               block_q: Optional[int] = None,
                tune=None, config=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Knob resolution + dispatch over the jitted `search_plan` — the
     facade's entry point (no deprecation warning; `search` is the warning
-    shim around this).  backend / round_leaves / pq_budget / dma_depth /
-    block_q default to None and resolve explicit arg > `config` field (an
+    shim around this).  backend / round_leaves / pq_budget / dma_depth
+    default to None and resolve explicit arg > `config` field (an
     IndexConfig — what FreshIndex.search passes) > `tune` (a
     kernels.autotune.TuneConfig — the FRESH tuned entry for this device,
     what FreshIndex.search passes when a table is installed) > the static
-    defaults 'ref' / 8 / uncapped / 1 / 1; stop_eps / stop_leaves are the
+    defaults 'ref' / 8 / uncapped / 1; stop_eps / stop_leaves are the
     repro.quality approximate stop rules (defaults = exact).
     Returns (Q,) arrays for k == 1, (Q, k) ascending otherwise."""
     t = tune
@@ -519,15 +573,12 @@ def run_search(idx: FlatIndex, queries: jnp.ndarray, *,
                               t.pq_budget if t else None)
     dd = _resolve_knob(dma_depth, config, "dma_depth",
                        t.dma_depth if t else 1)
-    bq = _resolve_knob(block_q, config, "block_q",
-                       t.block_q if t else 1)
     if bk != "pallas":
-        dd, bq = 1, 1        # ref ignores them; don't split its jit cache
+        dd = 1               # ref ignores it; don't split its jit cache
     d, i, _ = search_plan(idx, queries, k=k, round_leaves=K, znorm=znorm,
                           max_rounds=max_rounds, backend=bk,
                           pq_budget=pq_budget, stop_eps=stop_eps,
-                          stop_leaves=stop_leaves, dma_depth=dd,
-                          block_q=bq)
+                          stop_leaves=stop_leaves, dma_depth=dd)
     return squeeze_k(d, i, k)
 
 
@@ -608,7 +659,6 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
                        stop_eps: float = 0.0,
                        stop_leaves: Optional[int] = None,
                        dma_depth: Optional[int] = None,
-                       block_q: Optional[int] = None,
                        tune=None, config=None):
     """The PURE sharded search plan factory: `(idx, queries) -> (dist,
     ids, rounds)` with (Q, k) outputs and no squeeze — the sharded
@@ -632,13 +682,13 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
     per (batch-bucket, k, mesh layout) with `.lower().compile()`, so the
     two paths execute identical programs.
 
-    backend / round_leaves / pq_budget / dma_depth / block_q resolve from
+    backend / round_leaves / pq_budget / dma_depth resolve from
     `config` (IndexConfig) when unset, then from `tune` (a fresh autotune
     TuneConfig, the same fallback layer `run_search` uses), then from the
     hard defaults — like the local search().  backend='pallas' routes
     each device's refine closure through the fused kernels.refine_topk,
-    which is where dma_depth / block_q land; the ref backend ignores
-    them, so they are normalized to 1/1 there to keep one jit entry.
+    which is where dma_depth lands; the ref backend ignores it, so it
+    is normalized to 1 there to keep one jit entry.
 
     `stop_eps` / `stop_leaves` are the repro.quality approximate stop
     rules, lowered into the collective while_loop cond exactly like the
@@ -656,10 +706,8 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
                               t.pq_budget if t else None)
     dd = _resolve_knob(dma_depth, config, "dma_depth",
                        t.dma_depth if t else 1)
-    bq = _resolve_knob(block_q, config, "block_q",
-                       t.block_q if t else 1)
     if bk != "pallas":
-        dd, bq = 1, 1        # ref ignores them; don't split its jit cache
+        dd = 1               # ref ignores it; don't split its jit cache
     inv_eps, leaf_budget = _stop_knobs(stop_eps, stop_leaves, pq_budget)
 
     def _local_search(series, sq_norms, perm, leaf_lo, leaf_hi, q, q_paa, q_sq):
@@ -694,7 +742,7 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
             alive = lbs < bound[:, None]
             return _refine_round(q, q_sq, series, sq_norms, ids, alive,
                                  bsf_d, bsf_e, M=M, k=k, backend=bk,
-                                 dma_depth=dd, block_q=bq)
+                                 dma_depth=dd)
 
         def cond(state):
             cursor, bsf_d, _, pb, rounds = state
@@ -724,8 +772,7 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
         # recompute the local winners' distances in DIRECT form (matmul
         # form loses ~1e-3 absolute to f32 cancellation — see search())
         found = bsf_d < BIG
-        d_exact = jnp.sum(jnp.square(q[:, None, :] - series[bsf_e]), axis=-1)
-        d_local = jnp.where(found, d_exact, bsf_d)
+        d_local = jnp.where(found, direct_sq(q, series, bsf_e), bsf_d)
         ids_local = jnp.where(found, perm[bsf_e], -1)
 
         # final resolution: gather the n_dev local buffers, top-k the union
@@ -744,8 +791,7 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
     out2 = P(None, None)
 
     def sharded_plan_impl(idx: FlatIndex, queries: jnp.ndarray):
-        q, q_paa = prepare_queries(queries, znorm, index=idx)
-        q_sq = jnp.sum(q * q, axis=-1)
+        q, q_paa, q_sq = prepare_query_rows(queries, znorm, index=idx)
         fn = shard_map(
             _local_search, mesh=mesh,
             in_specs=(pleaf, P(axis), P(axis), pleaf, pleaf,
@@ -767,7 +813,6 @@ def build_sharded_search(mesh: Mesh, *, axis: str = "data", k: int = 1,
                          stop_eps: float = 0.0,
                          stop_leaves: Optional[int] = None,
                          dma_depth: Optional[int] = None,
-                         block_q: Optional[int] = None,
                          tune=None, config=None):
     """Builds a jitted sharded k-NN `search(idx, queries)` for the mesh.
 
@@ -781,7 +826,7 @@ def build_sharded_search(mesh: Mesh, *, axis: str = "data", k: int = 1,
         mesh, axis=axis, k=k, round_leaves=round_leaves,
         sync_every=sync_every, max_rounds=max_rounds, znorm=znorm,
         backend=backend, pq_budget=pq_budget, stop_eps=stop_eps,
-        stop_leaves=stop_leaves, dma_depth=dma_depth, block_q=block_q,
+        stop_leaves=stop_leaves, dma_depth=dma_depth,
         tune=tune, config=config))
 
     def sharded_search(idx: FlatIndex, queries: jnp.ndarray):

@@ -1,16 +1,14 @@
 """Shared kernel-module plumbing (a leaf module — no package imports, so
 every kernel module can use it without cycling through ops.py).
 
-INTERPRET resolves once per process: interpret mode (kernel body run in
-Python — bit-identical semantics, no Mosaic) everywhere except TPU, where
-kernels compile to Mosaic.
+Interpret mode (kernel body run in Python — bit-identical semantics, no
+Mosaic) is the CPU's way to execute a Pallas kernel; on TPU kernels
+compile to Mosaic.  The choice is made when a kernel is called, from the
+platform JAX reports then, not when this module is imported.
 
-Lowering dispatch: kernels with more than one compiled code path (today
-only `refine`, which has a Mosaic scalar-prefetch kernel AND a Triton
-grid-(Q,) kernel) resolve their path through `resolve_lowering`, which
-raises the typed `KernelLoweringError` — instead of an opaque
-Mosaic/Triton trace-time failure — when `backend="pallas"` is requested
-on a platform with no lowering path at all.
+`resolve_lowering` is the one dispatch point for `backend="pallas"`: it
+raises the typed `KernelLoweringError` — instead of an opaque Mosaic
+trace-time failure — on a platform with no lowering path at all.
 """
 
 from __future__ import annotations
@@ -19,94 +17,61 @@ from typing import Optional, Tuple
 
 import jax
 
-INTERPRET: bool = jax.default_backend() != "tpu"
-
 #: platform string (jax.default_backend() spelling) -> the compiled
-#: lowering path refine-style multi-backend kernels take there.  CPU is
-#: deliberately absent: it has NO compiled path — interpret mode is the
-#: only way to execute a Pallas kernel there, and `resolve_lowering`
-#: falls back to it rather than erroring.
-LOWERINGS = {
-    "tpu": "mosaic",
-    "gpu": "triton",
-    "cuda": "triton",
-    "rocm": "triton",
-}
-
-_KNOWN_LOWERINGS = ("mosaic", "triton")
+#: lowering path kernels take there.  CPU is deliberately absent: it has
+#: NO compiled path — interpret mode is the only way to execute a Pallas
+#: kernel there, and `resolve_lowering` falls back to it rather than
+#: erroring.
+LOWERINGS = {"tpu": "mosaic"}
 
 
 class KernelLoweringError(RuntimeError):
     """`backend="pallas"` was requested on a platform with no kernel
     lowering path (and interpret mode was explicitly disabled).  Raised
     at dispatch time with the platform and the supported set, so callers
-    see a clear capability error instead of a Mosaic/Triton trace-time
-    stack."""
+    see a clear capability error instead of a Mosaic trace-time stack."""
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """None -> the process default (Mosaic on TPU, interpreter elsewhere).
+    """None -> interpret everywhere except TPU (where Mosaic compiles).
 
     Raw kernels default interpret=None and resolve through this, so a
     direct caller never silently runs the Python interpreter on TPU.
     """
-    return INTERPRET if interpret is None else interpret
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def resolve_lowering(interpret: Optional[bool] = None,
                      lowering: Optional[str] = None,
                      platform: Optional[str] = None
                      ) -> Tuple[str, bool]:
-    """Resolve a multi-backend kernel's `(kernel structure, interpret)`.
+    """Resolve a kernel's `(lowering, interpret)` for a platform.
 
-    `lowering` picks the kernel STRUCTURE ('mosaic': scalar-prefetch
-    grid-(Q, K) accumulator kernel; 'triton': grid-(Q,) dynamic-gather
-    kernel — both also executable bit-identically under interpret mode);
-    `interpret` whether it compiles or runs in the Python interpreter.
-    Defaults (both None): TPU compiles Mosaic, GPU compiles Triton, CPU
-    interprets the Mosaic-structure kernel, and any OTHER platform
-    raises `KernelLoweringError` — the typed capability error the
-    `backend="pallas"` resolution contract promises (a platform like
-    'metal' must fail HERE, not five frames deep in a lowering trace).
+    `lowering` is 'mosaic' or None (the Mosaic structures are the only
+    ones; anything else is a ValueError); `interpret` whether the kernel
+    compiles or runs in the Python interpreter.  Defaults (both None):
+    TPU compiles Mosaic, CPU interprets, and any OTHER platform raises
+    `KernelLoweringError` — a platform like 'gpu' must fail HERE, not
+    five frames deep in a lowering trace.
 
     `platform` overrides `jax.default_backend()` (tests exercise the
     per-platform matrix without owning the hardware).
     """
-    if lowering is not None and lowering not in _KNOWN_LOWERINGS:
+    if lowering not in (None, "mosaic"):
         raise ValueError(
-            f"lowering must be one of {_KNOWN_LOWERINGS} or None, "
-            f"got {lowering!r}")
+            f"lowering must be 'mosaic' or None, got {lowering!r}")
     p = jax.default_backend() if platform is None else platform
     compiled = LOWERINGS.get(p)
     if interpret is None:
         # only CPU falls back to interpret mode by default; an unknown
-        # platform (e.g. 'metal') must fail the typed way below unless
-        # the caller opts into the interpreter explicitly
+        # platform must fail the typed way below unless the caller opts
+        # into the interpreter explicitly
         interpret = compiled is None and p == "cpu"
-    if lowering is None:
-        if compiled is not None:
-            lowering = compiled
-        elif interpret:
-            lowering = "mosaic"        # structure only; body runs in Python
-        else:
-            raise KernelLoweringError(
-                f"backend='pallas' has no kernel lowering path on "
-                f"platform {p!r} (supported: "
-                f"{sorted(set(LOWERINGS))} compile, 'cpu' interprets); "
-                f"pass backend='ref' or interpret=True")
-    if not interpret and compiled != lowering:
+    if not interpret and compiled is None:
         raise KernelLoweringError(
-            f"platform {p!r} cannot compile the {lowering!r} lowering "
-            f"(it compiles {compiled!r}) and interpret mode was "
-            f"explicitly disabled; supported compile platforms: "
-            f"{sorted(set(LOWERINGS))}")
-    return lowering, bool(interpret)
-
-
-def tpu_compiler_params(dimension_semantics: Tuple[str, ...]):
-    """Mosaic compiler params across jax versions (jax <= 0.4.x spells the
-    class TPUCompilerParams; newer jax renamed it CompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    return cls(dimension_semantics=dimension_semantics)
+            f"backend='pallas' has no kernel lowering path on "
+            f"platform {p!r} (supported: {sorted(LOWERINGS)} compile, "
+            f"'cpu' interprets); pass backend='ref' or interpret=True")
+    return "mosaic", bool(interpret)

@@ -2,13 +2,12 @@
 cache the winner next to checkpoints.
 
 The refine kernel's profitable knob settings are hardware facts — the
-Mosaic DMA ring depth that hides HBM latency, the Triton query-block
-rows that fill an SM, the `round_leaves` batch that amortizes one
-kernel launch — not index semantics, so they do not belong in code as
-static defaults.  This module measures them: `autotune_index` enumerates
-candidate `TuneConfig`s per lowering (`candidate_space`), times each one
-through the SAME jitted search plans serving dispatches (mirroring
-`quality.calibrate._run_setting`), and stores the fastest in an
+Mosaic DMA ring depth that hides HBM latency, the `round_leaves` batch
+that amortizes one kernel launch — not index semantics, so they do not
+belong in code as static defaults.  This module measures them:
+`autotune_index` enumerates candidate `TuneConfig`s (`candidate_space`),
+times each one through the SAME jitted search plans serving dispatches
+(mirroring `quality.calibrate._run_setting`), and stores the fastest in an
 `AutotuneTable` keyed by `(device_kind, L, leaf_capacity, dtype)` —
 the four facts that determine the kernel's shape.  `FreshIndex` persists
 the table with its checkpoint (`extra["autotune"]`) and resolves UNSET
@@ -50,7 +49,6 @@ DEFAULTS: Dict[str, Optional[int]] = {
     "round_leaves": 8,
     "pq_budget": None,
     "dma_depth": 1,
-    "block_q": 1,
 }
 
 
@@ -65,12 +63,10 @@ class TuneConfig:
     dma_depth     Mosaic HBM->VMEM DMA ring depth (pallas only; 1 = the
                   pipelined BlockSpec kernel, >= 2 = the explicit
                   double/multi-buffered ring)
-    block_q       Triton query rows per program (pallas only)
     """
     round_leaves: int = 8
     pq_budget: Optional[int] = None
     dma_depth: int = 1
-    block_q: int = 1
 
     def to_dict(self) -> dict:
         """Plain-dict form (JSON / checkpoint payload)."""
@@ -113,8 +109,8 @@ class TuneEntry:
 def device_kind() -> str:
     """The live accelerator's kind string — the table's first key part.
 
-    `jax.devices()[0].device_kind` where available (e.g. 'TPU v4',
-    'NVIDIA A100...'), else the platform name; lookups and stores go
+    `jax.devices()[0].device_kind` where available (e.g. 'TPU v5 lite'),
+    else the platform name; lookups and stores go
     through this one helper so they can never disagree on spelling.
     """
     import jax
@@ -210,47 +206,30 @@ def resolve_knobs(config, entry: Optional[TuneEntry] = None) -> TuneConfig:
 
     return TuneConfig(round_leaves=pick("round_leaves"),
                       pq_budget=pick("pq_budget"),
-                      dma_depth=pick("dma_depth"),
-                      block_q=pick("block_q"))
+                      dma_depth=pick("dma_depth"))
 
 
-def candidate_space(lowering: Optional[str] = None, *,
-                    quick: bool = False,
+def candidate_space(*, quick: bool = False,
                     round_leaves_grid: Optional[Sequence[int]] = None,
                     pq_budgets: Sequence[Optional[int]] = (None,),
-                    dma_depths: Optional[Sequence[int]] = None,
-                    block_qs: Optional[Sequence[int]] = None
+                    dma_depths: Optional[Sequence[int]] = None
                     ) -> Tuple[TuneConfig, ...]:
-    """Enumerate the sweep's candidate TuneConfigs for one lowering.
-
-    `lowering` is 'mosaic' / 'triton' / None (resolve for the live
-    platform); only the knobs that lowering reads are swept — Mosaic
-    varies `dma_depths`, Triton varies `block_qs` — crossed with
-    `round_leaves_grid` and `pq_budgets`.  `quick` shrinks every axis to
-    a two-point grid (the CI smoke leg).  The default config is always
-    candidate 0, so the sweep can never return an empty or
-    all-rejected space.
+    """Enumerate the sweep's candidate TuneConfigs: `dma_depths`
+    crossed with `round_leaves_grid` and `pq_budgets`.  `quick` shrinks
+    every axis to a two-point grid (the CI smoke leg).  The default
+    config is always candidate 0, so the sweep can never return an
+    empty or all-rejected space.
     """
-    from ._compat import resolve_lowering
-    if lowering is None:
-        lowering, _ = resolve_lowering()
     if round_leaves_grid is None:
         round_leaves_grid = (8, 16) if quick else (4, 8, 16)
     if dma_depths is None:
         dma_depths = (1, 2) if quick else (1, 2, 4)
-    if block_qs is None:
-        block_qs = (1, 2) if quick else (1, 4, 8)
     out = [TuneConfig()]
     for rl in round_leaves_grid:
         for pq in pq_budgets:
-            if lowering == "triton":
-                for bq in block_qs:
-                    out.append(TuneConfig(round_leaves=rl, pq_budget=pq,
-                                          block_q=bq))
-            else:
-                for dd in dma_depths:
-                    out.append(TuneConfig(round_leaves=rl, pq_budget=pq,
-                                          dma_depth=dd))
+            for dd in dma_depths:
+                out.append(TuneConfig(round_leaves=rl, pq_budget=pq,
+                                      dma_depth=dd))
     seen, uniq = set(), []
     for c in out:
         if c not in seen:
@@ -266,10 +245,9 @@ def _run_tuned(index, qj, k: int, tc: TuneConfig, backend: str):
     from repro.core.search import search_plan, snapshot_search
 
     core, delta, alive, id0 = index.search_view()
-    dd, bq = (tc.dma_depth, tc.block_q) if backend == "pallas" else (1, 1)
+    dd = tc.dma_depth if backend == "pallas" else 1
     kw = dict(k=k, round_leaves=tc.round_leaves, znorm=index.config.znorm,
-              backend=backend, pq_budget=tc.pq_budget,
-              dma_depth=dd, block_q=bq)
+              backend=backend, pq_budget=tc.pq_budget, dma_depth=dd)
     if delta is None:
         d, i, _ = search_plan(core, qj, **kw)
     else:

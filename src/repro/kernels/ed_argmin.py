@@ -45,6 +45,7 @@ def _ed_kernel(q_ref, x_ref, min_ref, arg_ref, *, block_n: int):
     q_sq = jnp.sum(q * q, axis=1, keepdims=True)  # (BQ, 1)
     x_sq = jnp.sum(x * x, axis=1)[None, :]        # (1, BN)
     dots = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     d2 = jnp.maximum(q_sq + x_sq - 2.0 * dots, 0.0)          # (BQ, BN)
 
@@ -64,7 +65,7 @@ def ed_argmin(q: jnp.ndarray, xs: jnp.ndarray, *, block_q: int = 128,
               block_n: int = 512, interpret: bool = None):
     """q: (Q, L), xs: (N, L) -> ((Q,) min d^2 f32, (Q,) argmin i32).
 
-    interpret=None resolves via _compat.INTERPRET (Mosaic on TPU).
+    interpret=None resolves via _compat.resolve_interpret (Mosaic on TPU).
     """
     from ._compat import resolve_interpret
     interpret = resolve_interpret(interpret)
@@ -79,11 +80,6 @@ def ed_argmin(q: jnp.ndarray, xs: jnp.ndarray, *, block_q: int = 128,
     xs = jnp.pad(xs.astype(jnp.float32), ((0, Np - N), (0, 0)),
                  constant_values=1e10)
 
-    kwargs = {}
-    if not interpret:
-        from ._compat import tpu_compiler_params
-        kwargs["compiler_params"] = tpu_compiler_params(
-            ("parallel", "arbitrary"))
     dmin, arg = pl.pallas_call(
         functools.partial(_ed_kernel, block_n=bn),
         grid=(Qp // bq, Np // bn),
@@ -100,6 +96,7 @@ def ed_argmin(q: jnp.ndarray, xs: jnp.ndarray, *, block_q: int = 128,
             jax.ShapeDtypeStruct((Qp, 1), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(q, xs)
     return dmin[:Q, 0], arg[:Q, 0]

@@ -64,7 +64,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = 128, interpret: bool = None):
     """q: (B, Hq, T, dh); k/v: (B, Hkv, S, dh) -> (B, Hq, T, dh).
 
-    interpret=None resolves via _compat.INTERPRET (Mosaic on TPU).
+    interpret=None resolves via _compat.resolve_interpret (Mosaic on TPU).
     """
     from ._compat import resolve_interpret
     interpret = resolve_interpret(interpret)

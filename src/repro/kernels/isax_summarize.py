@@ -9,13 +9,17 @@ compares against the breakpoint table) is free next to the memory stream.
 Tiling: grid over row blocks of BN series; each block holds a (BN, L) f32
 tile in VMEM (BN=256, L=256 -> 256 KiB, comfortably inside the ~16 MiB v5e
 VMEM even with double buffering).  L is a multiple of 128 => lane-aligned.
-Outputs are (BN, w) tiles; w=16 underfills the 128-lane register tile — an
-accepted inefficiency since outputs are 16x smaller than inputs and the
-kernel is input-bandwidth-bound.
+Outputs are written TRANSPOSED, as (w, BN) tiles of (w, n) arrays: series
+run along the lanes.  An (n, w) output would be padded from w=16 to 128
+lanes in HBM — 2 GiB per output at n = 2^22 instead of 256 MiB.
 
-The breakpoint table (2^bits - 1 values) rides in VMEM replicated per block
-(1 KiB); quantization is sum_k [paa > bp_k] — a dense compare-reduce that
-vectorizes perfectly, replacing the host searchsorted.
+z-normalization (optional) uses isax.znormalize's formula: mean, then the
+root mean squared deviation, then (x - mu) / (sd + 1e-8).  PAA is the
+(w, L) x (BN, L)^T matmul against a constant averaging matrix (1/seg on
+the segment's columns), at HIGHEST precision so it keeps f32 accuracy on
+the MXU.  The breakpoint table (2^bits - 1 scalars) rides in SMEM;
+quantization is sum_b [paa > bp_b], one (w, BN) compare-add per
+breakpoint, replacing the host searchsorted.
 """
 
 from __future__ import annotations
@@ -26,26 +30,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import isax
 
 
-def _summarize_kernel(x_ref, bp_ref, paa_ref, word_ref, *, segments: int,
+def _summarize_kernel(x_ref, avg_ref, bp_ref, paa_ref, word_ref, *,
                       znorm: bool):
     x = x_ref[...].astype(jnp.float32)            # (BN, L)
     if znorm:
         mu = jnp.mean(x, axis=1, keepdims=True)
-        # E[x^2] - mu^2 form: one pass over the tile, no second reduction
-        var = jnp.mean(x * x, axis=1, keepdims=True) - mu * mu
-        x = (x - mu) / (jnp.sqrt(jnp.maximum(var, 0.0)) + 1e-8)
-    bn, L = x.shape
-    seg = L // segments
-    p = jnp.mean(x.reshape(bn, segments, seg), axis=2)     # (BN, w)
+        x = x - mu
+        sd = jnp.sqrt(jnp.mean(x * x, axis=1, keepdims=True))
+        x = x / (sd + 1e-8)
+    # PAA as one matmul against the averaging matrix (Mosaic cannot split
+    # the lane dim into (w, L/w) for a segment mean), series on the lanes
+    p = jax.lax.dot_general(avg_ref[...], x, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # (w, BN)
     paa_ref[...] = p
-    bp = bp_ref[...]                                       # (1, 2^bits - 1)
     # symbol = #breakpoints strictly below the PAA value
-    word_ref[...] = jnp.sum(
-        (p[:, :, None] > bp[0][None, None, :]).astype(jnp.int32), axis=2)
+    sym = jnp.zeros(p.shape, jnp.int32)
+    for b in range(bp_ref.shape[1]):       # static unroll, (w, BN) each
+        sym = sym + (p > bp_ref[0, b]).astype(jnp.int32)
+    word_ref[...] = sym
 
 
 @functools.partial(jax.jit, static_argnames=("segments", "bits", "znorm",
@@ -55,7 +63,7 @@ def summarize(x: jnp.ndarray, *, segments: int = isax.SEGMENTS,
               block_rows: int = 256, interpret: bool = None):
     """x: (n, L) -> (paa (n, w) f32, words (n, w) i32).  Pads n internally.
 
-    interpret=None resolves via _compat.INTERPRET (Mosaic on TPU).
+    interpret=None resolves via _compat.resolve_interpret (Mosaic on TPU).
     """
     from ._compat import resolve_interpret
     interpret = resolve_interpret(interpret)
@@ -66,23 +74,28 @@ def summarize(x: jnp.ndarray, *, segments: int = isax.SEGMENTS,
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)), constant_values=1.0)
     bp = jnp.asarray(isax.breakpoints(bits), jnp.float32)[None, :]
+    seg = L // segments
+    avg = jnp.asarray(np.repeat(np.eye(segments, dtype=np.float32), seg,
+                                axis=1) / seg)                # (w, L)
 
     grid = (n_pad // bn,)
     paa, words = pl.pallas_call(
-        functools.partial(_summarize_kernel, segments=segments, znorm=znorm),
+        functools.partial(_summarize_kernel, znorm=znorm),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, L), lambda i: (i, 0)),
-            pl.BlockSpec((1, (1 << bits) - 1), lambda i: (0, 0)),
+            pl.BlockSpec((segments, L), lambda i: (0, 0)),
+            pl.BlockSpec((1, (1 << bits) - 1), lambda i: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((bn, segments), lambda i: (i, 0)),
-            pl.BlockSpec((bn, segments), lambda i: (i, 0)),
+            pl.BlockSpec((segments, bn), lambda i: (0, i)),
+            pl.BlockSpec((segments, bn), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, segments), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, segments), jnp.int32),
+            jax.ShapeDtypeStruct((segments, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((segments, n_pad), jnp.int32),
         ],
         interpret=interpret,
-    )(x, bp)
-    return paa[:n], words[:n]
+    )(x, avg, bp)
+    return paa[:, :n].T, words[:, :n].T

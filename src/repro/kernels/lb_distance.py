@@ -7,11 +7,15 @@ move).  Per segment: max(lo - q, 0) + max(q - hi, 0), squared, summed over
 w, scaled by L/w.
 
 Tiling: grid (Q/BQ, NL/BL).  Per block: q tile (BQ, w), lo/hi tiles
-(BL, w), output tile (BQ, BL).  The (BQ, BL, w) broadcast intermediate
-lives in VREGs/VMEM: BQ=128, BL=256, w=16 -> 32 MiB f32 would be too big as
-a materialized array, so the kernel loops over segments with an accumulator
-instead — w is tiny and static, so a Python loop unrolls into 16 fused
-multiply-adds over (BQ, BL) tiles (lane-aligned: BL multiple of 128).
+(w, BL) — the leaf regions ride in TRANSPOSED, so segment s of every leaf
+is one lane-dense (1, BL) row — and output tile (BQ, BL).  The (BQ, BL, w)
+broadcast intermediate would be 2 MiB f32 at BQ=128, BL=256, w=16, so the
+kernel loops over segments with an accumulator instead — w is tiny and
+static, so a Python loop unrolls into 16 fused multiply-adds over (BQ, BL)
+tiles, each broadcasting a (BQ, 1) query column against a (1, BL) leaf
+row.  (Slicing segment s as a column of a (BL, w) tile and turning it
+into a lane row is a transpose per segment, which Mosaic's compiler did
+not finish compiling.)
 """
 
 from __future__ import annotations
@@ -26,15 +30,12 @@ from repro.core import isax
 
 
 def _lb_kernel(q_ref, lo_ref, hi_ref, out_ref, *, scale: float):
-    q = q_ref[...]            # (BQ, w)
-    lo = lo_ref[...]          # (BL, w)
-    hi = hi_ref[...]          # (BL, w)
-    w = q.shape[1]
-    acc = jnp.zeros((q.shape[0], lo.shape[0]), jnp.float32)
+    w = q_ref.shape[1]        # q (BQ, w); lo/hi (w, BL)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
     for s in range(w):        # static unroll: w fused (BQ, BL) FMAs
-        qs = q[:, s][:, None]           # (BQ, 1)
-        los = lo[:, s][None, :]         # (1, BL)
-        his = hi[:, s][None, :]
+        qs = q_ref[:, pl.ds(s, 1)]      # (BQ, 1)
+        los = lo_ref[pl.ds(s, 1), :]    # (1, BL)
+        his = hi_ref[pl.ds(s, 1), :]
         d = jnp.maximum(los - qs, 0.0) + jnp.maximum(qs - his, 0.0)
         acc = acc + d * d
     out_ref[...] = acc * scale
@@ -48,7 +49,7 @@ def lb_distance(q_paa: jnp.ndarray, leaf_lo: jnp.ndarray,
                 interpret: bool = None) -> jnp.ndarray:
     """(Q, w) x (NL, w) -> (Q, NL) squared lower bounds.
 
-    interpret=None resolves via _compat.INTERPRET (Mosaic on TPU,
+    interpret=None resolves via _compat.resolve_interpret (Mosaic on TPU,
     interpreter elsewhere) — a hard-coded True would silently run the
     Python interpreter for direct callers even on TPU.
     """
@@ -68,16 +69,16 @@ def lb_distance(q_paa: jnp.ndarray, leaf_lo: jnp.ndarray,
     leaf_hi = jnp.pad(leaf_hi.astype(jnp.float32), ((0, NLp - NL), (0, 0)),
                       constant_values=big)
     # clamp infinities (inf - inf = nan inside the kernel's FMA form)
-    leaf_lo = jnp.clip(leaf_lo, -big, big)
-    leaf_hi = jnp.clip(leaf_hi, -big, big)
+    leaf_lo = jnp.clip(leaf_lo, -big, big).T             # (w, NLp)
+    leaf_hi = jnp.clip(leaf_hi, -big, big).T
 
     out = pl.pallas_call(
         functools.partial(_lb_kernel, scale=float(series_len) / w),
         grid=(Qp // bq, NLp // bl),
         in_specs=[
             pl.BlockSpec((bq, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bl, w), lambda i, j: (j, 0)),
-            pl.BlockSpec((bl, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bl), lambda i, j: (0, j)),
+            pl.BlockSpec((w, bl), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bq, bl), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Qp, NLp), jnp.float32),

@@ -1,17 +1,17 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode (kernel body
-run in Python — bit-identical semantics, no Mosaic); on TPU they compile to
-Mosaic.  `INTERPRET` (re-exported from _compat) resolves the default once
-per process; every op also takes an explicit override for tests.  The raw
-kernel modules default `interpret=None` and resolve through
-_compat.resolve_interpret too, so a direct caller gets Mosaic on TPU
-instead of silently running the Python interpreter.
+On CPU the kernels execute in interpret mode (kernel body run in Python —
+bit-identical semantics, no Mosaic); on TPU they compile to Mosaic.
+`_compat.resolve_interpret` picks the default from the platform at call
+time; every op also takes an explicit override for tests.  The raw kernel
+modules default `interpret=None` and resolve through it too, so a direct
+caller gets Mosaic on TPU instead of silently running the Python
+interpreter.
 """
 
 from __future__ import annotations
 
-from ._compat import INTERPRET, resolve_interpret  # noqa: F401
+from ._compat import resolve_interpret
 from .ed_argmin import ed_argmin as _ed_argmin
 from .isax_summarize import summarize as _summarize
 from .lb_distance import lb_distance as _lb_distance
@@ -42,16 +42,15 @@ def ed_argmin(q, xs, *, interpret=None):
 
 def refine_topk(q, q_sq, series, sq_norms, leaf_ids, alive, bsf_d, bsf_e,
                 *, leaf_capacity, k, interpret=None, dma_depth=1,
-                block_q=1, lowering=None):
-    # interpret is passed through RAW (not pre-resolved): refine is the
-    # one multi-lowering kernel, and _compat.resolve_lowering must see
-    # `None` to pick (structure, interpret) per platform — TPU compiles
-    # Mosaic, GPU compiles Triton, CPU interprets, anything else raises
-    # the typed KernelLoweringError at dispatch time.
+                lowering=None):
+    # interpret is passed through RAW (not pre-resolved):
+    # _compat.resolve_lowering must see `None` to pick interpret per
+    # platform — TPU compiles Mosaic, CPU interprets, anything else
+    # raises the typed KernelLoweringError at dispatch time.
     return _refine_topk(q, q_sq, series, sq_norms, leaf_ids, alive,
                         bsf_d, bsf_e, leaf_capacity=leaf_capacity, k=k,
                         interpret=interpret, dma_depth=dma_depth,
-                        block_q=block_q, lowering=lowering)
+                        lowering=lowering)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,

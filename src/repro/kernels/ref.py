@@ -46,7 +46,7 @@ def ed_argmin_ref(q: jnp.ndarray, xs: jnp.ndarray
     q = q.astype(jnp.float32)
     xs = xs.astype(jnp.float32)
     d2 = (jnp.sum(q * q, -1)[:, None] + jnp.sum(xs * xs, -1)[None, :]
-          - 2.0 * q @ xs.T)
+          - 2.0 * jnp.dot(q, xs.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
     i = jnp.argmin(d2, axis=1).astype(jnp.int32)
     return jnp.take_along_axis(d2, i[:, None].astype(jnp.int32), 1)[:, 0], i
@@ -74,6 +74,7 @@ def refine_topk_ref(q: jnp.ndarray, q_sq: jnp.ndarray, series: jnp.ndarray,
     xs = jnp.take(series, entry, axis=0).astype(jnp.float32)
     xn = jnp.take(sq_norms, entry, axis=0).astype(jnp.float32)
     dots = jnp.einsum("qnl,ql->qn", xs, q.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32)
     d2 = jnp.maximum(q_sq[:, None] + xn - 2.0 * dots, 0.0)
     d2 = jnp.where(jnp.repeat(alive.astype(bool), M, axis=1), d2, big)

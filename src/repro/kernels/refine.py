@@ -51,38 +51,33 @@ Buffer width: kp = k in interpret mode; on Mosaic the buffer is padded up
 to a 128-lane multiple (padded slots carry d=BIG, entry 0 — they sort
 after every real candidate and are sliced off by the wrapper).
 
-Lowerings (PR 10): the round has three kernel structures behind one
-wrapper, resolved through `_compat.resolve_lowering` and tuned by
-`kernels.autotune`:
+Block layout: the per-query operands ride in as (Q, 1, L), (Q, 1, 1) and
+(Q, 1, kp) arrays and the leaf norms as (NL, 1, M), with the leading dim
+squeezed out of each block.  Mosaic requires a block's last two dims to
+be (8, 128)-divisible or equal to the array's; a (1, L) row block of a
+(Q, L) array is neither, while the last two dims of these 3-D blocks are
+the whole trailing array dims.  The kernel body still sees (1, L) /
+(1, kp) / (1, M) rows.
 
-  mosaic, dma_depth=1   the grid-(Q, K) scalar-prefetch kernel above —
-                        the BlockSpec pipeliner double-buffers the leaf
-                        copies implicitly (one block look-ahead);
-  mosaic, dma_depth>=2  `series` stays in HBM (`pltpu.ANY`) and the
-                        kernel issues its own `make_async_copy` chain
-                        into a (depth, M, L) VMEM ring: the copy for PQ
-                        slot j+depth-1 is IN FLIGHT while slot j
-                        computes, and a pruned slot starts no copy at
-                        all (the explicit form of the forward-fill DMA
-                        elision).  Bit-identical fold, deeper overlap
-                        for leaves whose DMA latency exceeds one round
-                        of compute;
-  triton (GPU)          grid (ceil(Q/block_q),): each program owns
-                        block_q query rows, walks their K PQ slots with
-                        an in-kernel fori_loop, and gathers each (M, L)
-                        leaf block with a dynamic `pl.load` straight
-                        from GMEM (pointer arithmetic — the Triton
-                        analogue of the scalar-prefetch index_map).
-                        Dead slots fold masked BIG candidates, which the
-                        rank-select provably ignores.  The union width
-                        kp + M is padded to a power of two (Triton block
-                        shapes must be); padded slots behave like the
-                        Mosaic lane padding.
+Structures: the round has two Mosaic kernel structures behind one
+wrapper, selected by `dma_depth` and tuned by `kernels.autotune`:
 
-All three structures run under interpret mode on CPU, which is how CI
+  dma_depth=1   the grid-(Q, K) scalar-prefetch kernel above — the
+                BlockSpec pipeliner double-buffers the leaf copies
+                implicitly (one block look-ahead);
+  dma_depth>=2  `series` stays in HBM (`pltpu.HBM`) and the kernel
+                issues its own `make_async_copy` chain into a
+                (depth, M, L) VMEM ring: the copy for PQ slot j+depth-1
+                is IN FLIGHT while slot j computes, and a pruned slot
+                starts no copy at all (the explicit form of the
+                forward-fill DMA elision).  Bit-identical fold, deeper
+                overlap for leaves whose DMA latency exceeds one round of
+                compute.
+
+Both structures run under interpret mode on CPU, which is how CI
 exercises them without the hardware.  Exactness contract: the default
 structure is bit-identical to ref.refine_topk_ref (asserted by the test
-suite); the dma/triton variants return exactly the same ENTRIES in the
+suite); the DMA-ring variant returns exactly the same ENTRIES in the
 same order, with distances equal to the last ulp or so — XLA's dot
 merger batches a program's unrolled per-slot dots into one larger dot
 whose tail-lane reduction can differ by 1 ulp from the one-dot-per-
@@ -102,16 +97,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import resolve_lowering, tpu_compiler_params
+from ._compat import resolve_lowering
 
 BIG = 1e30
-
-
-def _pow2_pad(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
 
 
 def _rank_select(u_d: jnp.ndarray, u_e: jnp.ndarray, kp: int
@@ -154,6 +142,7 @@ def _refine_kernel(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref, bsfe_ref,
         xs = xs_ref[...].astype(jnp.float32)           # (M, L) leaf block
         xn = xn_ref[...]                               # (1, M)
         dots = jax.lax.dot_general(q, xs, (((1,), (1,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
                                    preferred_element_type=jnp.float32)
         d2 = jnp.maximum(qsq_ref[...] + xn - 2.0 * dots, 0.0)   # (1, M)
         cand_e = (ids_ref[i, j] * M
@@ -169,7 +158,7 @@ def _refine_kernel_dma(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
                        leaf_capacity: int, kp: int, depth: int,
                        n_slots: int):
     """Mosaic structure, explicit DMA ring: grid (Q,) — one program per
-    query row walks its K PQ slots with a fori_loop, keeping up to
+    query row walks its K PQ slots (statically unrolled), keeping up to
     `depth` leaf copies (HBM -> VMEM ring buffer) in flight ahead of the
     compute slot.  A pruned slot never starts a copy (explicit DMA
     elision; no forward-fill needed), and the fold under the wait is the
@@ -197,8 +186,8 @@ def _refine_kernel_dma(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
                 xs_hbm.at[pl.ds(ids_ref[i, j] * M, M), :],
                 xs_buf.at[slot], xs_sem.at[slot]).start()
             pltpu.make_async_copy(
-                xn_hbm.at[pl.ds(ids_ref[i, j], 1), :],
-                xn_buf.at[slot], xn_sem.at[slot]).start()
+                xn_hbm.at[pl.ds(ids_ref[i, j], 1)],
+                xn_buf.at[pl.ds(slot, 1)], xn_sem.at[slot]).start()
 
     for warm in range(depth - 1):          # fill the ring ahead of slot 0
         start(warm)
@@ -213,12 +202,13 @@ def _refine_kernel_dma(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
                 xs_hbm.at[pl.ds(ids_ref[i, j] * M, M), :],
                 xs_buf.at[slot], xs_sem.at[slot]).wait()
             pltpu.make_async_copy(
-                xn_hbm.at[pl.ds(ids_ref[i, j], 1), :],
-                xn_buf.at[slot], xn_sem.at[slot]).wait()
+                xn_hbm.at[pl.ds(ids_ref[i, j], 1)],
+                xn_buf.at[pl.ds(slot, 1)], xn_sem.at[slot]).wait()
             q = q_ref[...].astype(jnp.float32)             # (1, L)
             xs = xs_buf[slot].astype(jnp.float32)          # (M, L)
-            xn = xn_buf[slot]                              # (1, M)
+            xn = xn_buf[slot][:, :M]                       # (1, M)
             dots = jax.lax.dot_general(q, xs, (((1,), (1,)), ((), ())),
+                                       precision=jax.lax.Precision.HIGHEST,
                                        preferred_element_type=jnp.float32)
             d2 = jnp.maximum(qsq_ref[...] + xn - 2.0 * dots, 0.0)
             cand_e = (ids_ref[i, j] * M
@@ -228,45 +218,15 @@ def _refine_kernel_dma(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
             outd_ref[...], oute_ref[...] = _rank_select(u_d, u_e, kp)
 
 
-def _refine_kernel_triton(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
-                          bsfe_ref, xs_ref, xn_ref, outd_ref, oute_ref, *,
-                          leaf_capacity: int, kp: int, block_q: int,
-                          n_slots: int):
-    """Triton structure: grid (ceil(Q/block_q),) — each program owns
-    block_q query rows and gathers each (M, L) leaf block with a dynamic
-    pl.load from the full-array ref (GMEM pointer arithmetic; no
-    scalar-prefetch machinery exists on Triton).  Dead slots fold masked
-    BIG candidates — bit-identical to skipping, see the module docstring.
-    """
-    M = leaf_capacity
-    for r in range(block_q):               # static unroll over owned rows
-        q = pl.load(q_ref, (pl.dslice(r, 1), slice(None))
-                    ).astype(jnp.float32)                   # (1, L)
-        qsq = pl.load(qsq_ref, (pl.dslice(r, 1), slice(None)))
-        bd = pl.load(bsfd_ref, (pl.dslice(r, 1), slice(None)))  # (1, kp)
-        be = pl.load(bsfe_ref, (pl.dslice(r, 1), slice(None)))
-
-        # slot walk unrolled (n_slots = round_leaves, static and small):
-        # straight-line dots keep the reduction order bit-identical to
-        # the Mosaic kernels and the reference path
-        for j in range(n_slots):
-            leaf = ids_ref[r, j]
-            alv = alive_ref[r, j]
-            xs = pl.load(xs_ref, (pl.dslice(leaf * M, M), slice(None))
-                         ).astype(jnp.float32)              # (M, L)
-            xn = pl.load(xn_ref, (pl.dslice(leaf, 1), slice(None)))
-            dots = jax.lax.dot_general(q, xs, (((1,), (1,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-            d2 = jnp.maximum(qsq + xn - 2.0 * dots, 0.0)    # (1, M)
-            d2 = jnp.where(alv != 0, d2, BIG)               # mask, not skip
-            cand_e = (leaf * M
-                      + jax.lax.broadcasted_iota(jnp.int32, (1, M), 1))
-            u_d = jnp.concatenate([bd, d2], axis=1)
-            u_e = jnp.concatenate([be, cand_e], axis=1)
-            bd, be = _rank_select(u_d, u_e, kp)
-
-        pl.store(outd_ref, (pl.dslice(r, 1), slice(None)), bd)
-        pl.store(oute_ref, (pl.dslice(r, 1), slice(None)), be)
+def _leaf_norms(sq_norms, M: int, lanes: int = 0):
+    """(n_pad,) f32 norms -> (NL, 1, max(M, lanes)): one row per leaf,
+    whose block's last two dims equal the array's (see Block layout).
+    `lanes` zero-pads each row: an HBM array is tiled in 128-lane rows,
+    and a DMA may only slice whole tiles out of it."""
+    xn = sq_norms.astype(jnp.float32).reshape(-1, 1, M)
+    if lanes > M:
+        xn = jnp.pad(xn, ((0, 0), (0, 0), (0, lanes - M)))
+    return xn
 
 
 def _refine_mosaic(q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
@@ -276,7 +236,6 @@ def _refine_mosaic(q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
     elision."""
     Q, L = q.shape
     K = ids32.shape[1]
-    NL = series.shape[0] // M
     # DMA elision for pruned slots: a dead slot repeats the last alive
     # slot's leaf id (slot 0's id when the row starts dead — that block is
     # fetched at j == 0 regardless), so consecutive grid steps address the
@@ -286,149 +245,100 @@ def _refine_mosaic(q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
     slot = jnp.arange(K, dtype=jnp.int32)[None, :]
     last_alive = jax.lax.cummax(jnp.where(alive32 != 0, slot, -1), axis=1)
     ids32 = jnp.take_along_axis(ids32, jnp.maximum(last_alive, 0), axis=1)
-    xn = sq_norms.astype(jnp.float32).reshape(NL, M)
 
+    row = lambda i, j, ids, al: (i, 0, 0)              # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # leaf ids + alive mask
         grid=(Q, K),                           # j (PQ slot) innermost
         in_specs=[
-            pl.BlockSpec((1, L), lambda i, j, ids, al: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, j, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, j, ids, al: (i, 0)),
+            pl.BlockSpec((None, 1, L), row),
+            pl.BlockSpec((None, 1, 1), row),
+            pl.BlockSpec((None, 1, kp), row),
+            pl.BlockSpec((None, 1, kp), row),
             # the data-dependent gather: block row = the addressed leaf
             pl.BlockSpec((M, L), lambda i, j, ids, al: (ids[i, j], 0)),
-            pl.BlockSpec((1, M), lambda i, j, ids, al: (ids[i, j], 0)),
+            pl.BlockSpec((None, 1, M),
+                         lambda i, j, ids, al: (ids[i, j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, kp), lambda i, j, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, j, ids, al: (i, 0)),
+            pl.BlockSpec((None, 1, kp), row),
+            pl.BlockSpec((None, 1, kp), row),
         ],
     )
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = tpu_compiler_params(
-            ("parallel", "arbitrary"))
-    return pl.pallas_call(
+    out_d, out_e = pl.pallas_call(
         functools.partial(_refine_kernel, leaf_capacity=M, kp=kp),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, kp), jnp.float32),
-            jax.ShapeDtypeStruct((Q, kp), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, kp), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
-    )(ids32, alive32, q, q_sq[:, None], bsf_d, bsf_e, series, xn)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(ids32, alive32, q[:, None, :], q_sq[:, None, None], bsf_d[:, None, :],
+      bsf_e[:, None, :], series, _leaf_norms(sq_norms, M))
+    return out_d[:, 0, :], out_e[:, 0, :]
 
 
 def _refine_mosaic_dma(q, q_sq, series, sq_norms, ids32, alive32, bsf_d,
                        bsf_e, *, M: int, kp: int, depth: int,
                        interpret: bool):
-    """dma_depth >= 2: series stays in HBM (pltpu.ANY) and the kernel
+    """dma_depth >= 2: series stays in HBM (pltpu.HBM) and the kernel
     drives its own `depth`-deep make_async_copy ring."""
     Q, L = q.shape
     K = ids32.shape[1]
-    NL = series.shape[0] // M
-    xn = sq_norms.astype(jnp.float32).reshape(NL, M)
+    Mp = -(-M // 128) * 128                 # norm rows in whole lane tiles
 
+    row = lambda i, ids, al: (i, 0, 0)                 # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Q,),
         in_specs=[
-            pl.BlockSpec((1, L), lambda i, ids, al: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, ids, al: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # series: stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),      # leaf norms
+            pl.BlockSpec((None, 1, L), row),
+            pl.BlockSpec((None, 1, 1), row),
+            pl.BlockSpec((None, 1, kp), row),
+            pl.BlockSpec((None, 1, kp), row),
+            pl.BlockSpec(memory_space=pltpu.HBM),      # series: stay in HBM
+            pl.BlockSpec(memory_space=pltpu.HBM),      # leaf norms
         ],
         out_specs=[
-            pl.BlockSpec((1, kp), lambda i, ids, al: (i, 0)),
-            pl.BlockSpec((1, kp), lambda i, ids, al: (i, 0)),
+            pl.BlockSpec((None, 1, kp), row),
+            pl.BlockSpec((None, 1, kp), row),
         ],
         scratch_shapes=[
             # ring in the STORED dtype — the copy moves leaf bytes as-is
             # (bf16 leaves stream at bf16 width); the fold casts to f32
             pltpu.VMEM((depth, M, L), series.dtype),   # leaf block ring
-            pltpu.VMEM((depth, 1, M), jnp.float32),    # leaf norm ring
+            pltpu.VMEM((depth, 1, Mp), jnp.float32),   # leaf norm ring
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
     )
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = tpu_compiler_params(("arbitrary",))
-    return pl.pallas_call(
+    out_d, out_e = pl.pallas_call(
         functools.partial(_refine_kernel_dma, leaf_capacity=M, kp=kp,
                           depth=depth, n_slots=K),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, kp), jnp.float32),
-            jax.ShapeDtypeStruct((Q, kp), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, kp), jnp.int32),
         ],
         interpret=interpret,
-        **kwargs,
-    )(ids32, alive32, q, q_sq[:, None], bsf_d, bsf_e, series, xn)
-
-
-def _refine_triton(q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
-                   *, M: int, kp: int, block_q: int, interpret: bool):
-    """Triton structure: pad Q to a block_q multiple (padded rows are
-    all-dead with BIG buffers — pure identity folds), launch one program
-    per query block, slice the padding back off."""
-    Q, L = q.shape
-    K = ids32.shape[1]
-    NL = series.shape[0] // M
-    xn = sq_norms.astype(jnp.float32).reshape(NL, M)
-
-    Qp = -(-Q // block_q) * block_q
-    if Qp != Q:
-        pad = ((0, Qp - Q), (0, 0))
-        q = jnp.pad(q, pad)
-        ids32 = jnp.pad(ids32, pad)
-        alive32 = jnp.pad(alive32, pad)                # padded rows dead
-        bsf_d = jnp.pad(bsf_d, pad, constant_values=BIG)
-        bsf_e = jnp.pad(bsf_e, pad)
-    qsq = jnp.pad(q_sq[:, None], ((0, Qp - Q), (0, 0)))
-
-    out_d, out_e = pl.pallas_call(
-        functools.partial(_refine_kernel_triton, leaf_capacity=M, kp=kp,
-                          block_q=block_q, n_slots=K),
-        grid=(Qp // block_q,),
-        in_specs=[
-            pl.BlockSpec((block_q, K), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, K), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, L), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, kp), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, kp), lambda i: (i, 0)),
-            # full-array refs: the kernel body gathers with dynamic
-            # pl.load (GMEM pointers on Triton; materialized in interpret)
-            pl.BlockSpec((NL * M, L), lambda i: (0, 0)),
-            pl.BlockSpec((NL, M), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_q, kp), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, kp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Qp, kp), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, kp), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ids32, alive32, q, qsq, bsf_d, bsf_e, series, xn)
-    return out_d[:Q], out_e[:Q]
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(ids32, alive32, q[:, None, :], q_sq[:, None, None], bsf_d[:, None, :],
+      bsf_e[:, None, :], series, _leaf_norms(sq_norms, M, Mp))
+    return out_d[:, 0, :], out_e[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("leaf_capacity", "k",
                                              "interpret", "dma_depth",
-                                             "block_q", "lowering"))
+                                             "lowering"))
 def refine_topk(q: jnp.ndarray, q_sq: jnp.ndarray, series: jnp.ndarray,
                 sq_norms: jnp.ndarray, leaf_ids: jnp.ndarray,
                 alive: jnp.ndarray, bsf_d: jnp.ndarray, bsf_e: jnp.ndarray,
                 *, leaf_capacity: int, k: int,
                 interpret: Optional[bool] = None,
-                dma_depth: int = 1, block_q: int = 1,
+                dma_depth: int = 1,
                 lowering: Optional[str] = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One fused refinement round.
@@ -440,42 +350,22 @@ def refine_topk(q: jnp.ndarray, q_sq: jnp.ndarray, series: jnp.ndarray,
     leaf_ids: (Q, K) i32 leaves to visit this round (PQ order)
     alive:    (Q, K) bool/int — lb < round-start k-th BSF (pruning mask)
     bsf_d/e:  (Q, k) carried top-k buffer (ascending) / entry ids
-    dma_depth: Mosaic structure only — 1 uses the pipelined BlockSpec
-              kernel; >= 2 the explicit `depth`-deep DMA-ring kernel.
-    block_q:  Triton structure only — query rows per program.
-    lowering: kernel structure override ('mosaic' | 'triton' | None);
-              None resolves per platform via _compat.resolve_lowering.
+    dma_depth: 1 uses the pipelined BlockSpec kernel; >= 2 the explicit
+              `depth`-deep DMA-ring kernel.
+    lowering: None or 'mosaic' (the only structure family); anything
+              else raises ValueError.
     -> the merged (Q, k) buffer, same semantics as the reference
        ref.refine_topk_ref round, with no (Q, K*M, L) intermediate.
-       Every (lowering, dma_depth, block_q) combination returns the same
-       entries in the same order; the default structure is additionally
-       bit-identical in distances (see the module docstring's exactness
-       contract).
+       Every dma_depth returns the same entries in the same order; the
+       default structure is additionally bit-identical in distances
+       (see the module docstring's exactness contract).
     """
-    lowering, interpret = resolve_lowering(interpret, lowering)
+    _, interpret = resolve_lowering(interpret, lowering)
     if dma_depth < 1:
         raise ValueError(f"dma_depth must be >= 1, got {dma_depth}")
-    if block_q < 1:
-        raise ValueError(f"block_q must be >= 1, got {block_q}")
-    if lowering == "mosaic" and block_q != 1:
-        raise ValueError(
-            f"block_q={block_q} is a Triton-structure knob; the Mosaic "
-            f"structure processes one query row per program (block_q=1)")
-    if lowering == "triton" and dma_depth != 1:
-        raise ValueError(
-            f"dma_depth={dma_depth} is a Mosaic-structure knob; Triton "
-            f"pipelines its gathers in hardware (dma_depth=1)")
-    Q, L = q.shape
     K = leaf_ids.shape[1]
     M = leaf_capacity
-    if lowering == "triton":
-        # Triton block shapes must be powers of two: pad the union width
-        # kp + M up, so the buffer carries (pow2 - M) BIG/0 filler slots
-        # that sort after every real candidate (same trick as the Mosaic
-        # lane padding, different alignment rule).  Applied in interpret
-        # mode too, so CI exercises the compiled shape logic.
-        kp = max(_pow2_pad(k + M) - M, k)
-    elif interpret:
+    if interpret:
         kp = k                      # exact width in interpret mode
     else:
         kp = -(-k // 128) * 128     # lane-pad the buffer on Mosaic
@@ -486,11 +376,7 @@ def refine_topk(q: jnp.ndarray, q_sq: jnp.ndarray, series: jnp.ndarray,
     ids32 = leaf_ids.astype(jnp.int32)
     alive32 = alive.astype(jnp.int32)
 
-    if lowering == "triton":
-        out_d, out_e = _refine_triton(
-            q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
-            M=M, kp=kp, block_q=block_q, interpret=interpret)
-    elif dma_depth >= 2 and K >= 2:
+    if dma_depth >= 2 and K >= 2:
         out_d, out_e = _refine_mosaic_dma(
             q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
             M=M, kp=kp, depth=min(dma_depth, K), interpret=interpret)

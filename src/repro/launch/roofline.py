@@ -282,38 +282,32 @@ def model_flops_for(cfg, shape) -> float:
 # ---------------------------------------------------------------------- #
 # refine-kernel roofline: the asserted %-of-roofline bench number
 # ---------------------------------------------------------------------- #
-#: nominal (peak_flops, hbm_bytes_per_s) per device-kind SUBSTRING.
-#: Matched case-insensitively against `jax.devices()[0].device_kind`;
-#: unknown kinds fall back to the per-chip TPU constants from
-#: launch.mesh (the denominators every dry-run number already uses).
-#: The cpu entry is a nominal modern-server figure — on CPU the kernels
-#: run in interpret mode, so `kernels/refine/roofline_frac` is a tiny
-#: correctness-trace number there, gated only as present-and-positive;
-#: on real accelerators the same row becomes a regression-gated
-#: fraction of hardware peak.
+#: (peak_flops, hbm_bytes_per_s) per `jax.devices()[0].device_kind`,
+#: matched exactly.  A kind missing here is an error, never a default.
+#:   "TPU v5 lite" (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM — Google
+#:       Cloud documentation, "TPU v5e".
+#:   "cpu": a nominal server figure, not a published peak — on CPU the
+#:       kernels run in interpret mode, so a roofline fraction there is
+#:       a correctness trace, never a device metric.
 DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (2.0e11, 5.0e10),
-    "tpu": (PEAK_FLOPS_BF16, HBM_BW),
-    "a100": (312e12, 1555e9),
-    "h100": (989e12, 3350e9),
-    "v100": (125e12, 900e9),
 }
 
 
 def device_peaks(kind: Optional[str] = None) -> Tuple[float, float]:
     """(peak_flops, hbm_bytes_per_s) for device kind `kind` (None = the
-    live device).  Substring match over `DEVICE_PEAKS`; unknown kinds
-    fall back to the TPU per-chip constants, so the fraction stays
-    computable (and comparable to the dry-run tables) everywhere."""
+    live device's `device_kind`).  Raises KeyError for a kind that is
+    not in `DEVICE_PEAKS`: a roofline share against a guessed peak is a
+    wrong number."""
     if kind is None:
         import jax
-        d = jax.devices()[0]
-        kind = str(getattr(d, "device_kind", None) or jax.default_backend())
-    low = kind.lower()
-    for sub, peaks in DEVICE_PEAKS.items():
-        if sub in low:
-            return peaks
-    return PEAK_FLOPS_BF16, HBM_BW
+        kind = str(jax.devices()[0].device_kind)
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None
 
 
 def refine_analytic(Q: int, K: int, M: int, L: int, k: int,

@@ -313,9 +313,9 @@ def _run_setting(index, q, k: int, rule: StopRule, backend: Optional[str],
     # program it certifies
     kn = index.search_knobs()
     K = kn.round_leaves
-    dd, bq = (kn.dma_depth, kn.block_q) if bk == "pallas" else (1, 1)
+    dd = kn.dma_depth if bk == "pallas" else 1
     kw = dict(k=k, round_leaves=K, znorm=cfg.znorm, backend=bk,
-              pq_budget=kn.pq_budget, dma_depth=dd, block_q=bq,
+              pq_budget=kn.pq_budget, dma_depth=dd,
               **rule.lower())
     qj = jnp.asarray(q)
 

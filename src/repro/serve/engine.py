@@ -448,8 +448,7 @@ class QueryEngine:
             pq_budget=(cfg.pq_budget if cfg.pq_budget is not None
                        else kn.pq_budget),
             sync_every=cfg.sync_every,
-            dma_depth=kn.dma_depth if bk == "pallas" else 1,
-            block_q=kn.block_q if bk == "pallas" else 1)
+            dma_depth=kn.dma_depth if bk == "pallas" else 1)
         self.plans = PlanCache(donate=cfg.donate)
         self._batcher = MicroBatcher(cfg.max_batch)
         self._cv = threading.Condition(threading.RLock())

@@ -54,7 +54,7 @@ from repro.runtime.sharding import mesh_sig
 
 _PLAN_STATICS = ("k", "round_leaves", "znorm", "max_rounds", "backend",
                  "pq_budget", "stop_eps", "stop_leaves",
-                 "dma_depth", "block_q")
+                 "dma_depth")
 _SNAP_STATICS = _PLAN_STATICS + ("n_base",)
 
 
@@ -68,8 +68,8 @@ class Knobs:
     all-reduce cadence); local plans ignore it.  `stop_eps` /
     `stop_leaves` are the approximate-search early-termination knobs
     (repro.quality.StopRule.lower()); their defaults compile the exact
-    program.  `dma_depth` / `block_q` are the autotune-resolved kernel
-    knobs (Mosaic DMA ring depth, Triton query-block rows): resolved
+    program.  `dma_depth` is the autotune-resolved kernel knob (Mosaic
+    DMA ring depth): resolved
     from the index's AutotuneTable at engine construction, so a retuned
     table changes this dataclass and therefore — via `plan_key` — can
     never alias a stale AOT plan or result-cache entry."""
@@ -82,7 +82,6 @@ class Knobs:
     stop_eps: float = 0.0
     stop_leaves: Optional[int] = None
     dma_depth: int = 1
-    block_q: int = 1
 
 
 def plan_key(k: int, knobs: Knobs) -> tuple:
@@ -118,6 +117,11 @@ class CompiledPlan:
         self.k = k
         self.calls = 0
 
+    def as_text(self) -> str:
+        """The compiled program's HLO text (e.g. to check that a Mosaic
+        kernel, `tpu_custom_call`, is in the served program)."""
+        return self._exe.as_text()
+
     def run(self, snapshot, queries: jnp.ndarray):
         self.calls += 1
         if self.has_alive:
@@ -150,6 +154,13 @@ class ShardedCompiledPlan:
         self.bucket_q = bucket_q
         self.k = k
         self.calls = 0
+
+    def as_text(self) -> str:
+        """HLO text of the core program, then the merge program if any."""
+        parts = [self._core.as_text()]
+        if self._merge is not None:
+            parts.append(self._merge.as_text())
+        return "\n".join(parts)
 
     def run(self, snapshot, queries: jnp.ndarray):
         self.calls += 1
@@ -240,8 +251,7 @@ class PlanCache:
                     pq_budget=knobs.pq_budget,
                     stop_eps=knobs.stop_eps,
                     stop_leaves=knobs.stop_leaves,
-                    dma_depth=knobs.dma_depth,
-                    block_q=knobs.block_q))
+                    dma_depth=knobs.dma_depth))
                 self._sharded_jits[key] = fn
             return fn
 
@@ -279,7 +289,7 @@ class PlanCache:
                   max_rounds=knobs.max_rounds, backend=knobs.backend,
                   pq_budget=knobs.pq_budget, stop_eps=knobs.stop_eps,
                   stop_leaves=knobs.stop_leaves,
-                  dma_depth=knobs.dma_depth, block_q=knobs.block_q)
+                  dma_depth=knobs.dma_depth)
         has_delta = snapshot.delta is not None
         if has_alive:
             lowered = self._jitted(True).lower(
@@ -293,6 +303,11 @@ class PlanCache:
             lowered = self._jitted(False).lower(snapshot.core, qs, **kw)
         return CompiledPlan(lowered.compile(), has_delta, has_alive,
                             bucket_q, k)
+
+    def compiled(self) -> list:
+        """The cached plans (CompiledPlan / ShardedCompiledPlan)."""
+        with self._lock:
+            return list(self._plans.values())
 
     # ------------------------------------------------------------------ #
     def stats(self) -> dict:

@@ -245,3 +245,17 @@ def test_prepare_queries_mismatch_raises(index, queries):
         prepare_queries(jnp.ones((2, 250)))
     q, q_paa = prepare_queries(jnp.asarray(queries), index=index.index)
     assert q_paa.shape[-1] == index.index.paa.shape[1]
+
+
+@pytest.mark.parametrize("Q", [1, 16, 128, 129])
+def test_answer_does_not_depend_on_the_batch(index, walks, Q):
+    """A query's answer is the same bits whether it rides alone or in a
+    batch of 300 (more than two QUERY_TILE tiles): queries are prepared
+    and their distances recomputed in fixed tiles, which is what lets an
+    engine bucket return the facade's bits on TPU."""
+    from repro.data.synthetic import query_workload
+    q = jnp.asarray(query_workload(walks, 300, noise_sigma=0.05, seed=5))
+    d_all, i_all = index.search(q, k=5)
+    d, i = index.search(q[-Q:], k=5)
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_all)[-Q:])
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(i_all)[-Q:])

@@ -59,9 +59,8 @@ def tuned(data):
     return plain, ix, table
 
 
-def _entry(rl=16, dd=2, bq=1):
-    return TuneEntry(config=TuneConfig(round_leaves=rl, dma_depth=dd,
-                                       block_q=bq),
+def _entry(rl=16, dd=2):
+    return TuneEntry(config=TuneConfig(round_leaves=rl, dma_depth=dd),
                      median_ms=1.0, baseline_ms=2.0,
                      n_candidates=3, n_exact=3)
 
@@ -136,7 +135,7 @@ def test_resolve_knobs_config_beats_table_beats_defaults():
     got = resolve_knobs(cfg, e)
     assert got.round_leaves == 32
     assert got.dma_depth == 2                # unset -> tuned entry
-    assert got.block_q == 1                  # unset, entry default
+    assert got.pq_budget is None             # unset, entry default
     assert resolve_knobs(None, e).round_leaves == 16
 
 
@@ -156,16 +155,12 @@ def test_unknown_device_falls_back_to_defaults(data):
 # candidate space
 # --------------------------------------------------------------------- #
 def test_candidate_space_shape():
-    for lowering, swept, pinned in (("mosaic", "dma_depth", "block_q"),
-                                    ("triton", "block_q", "dma_depth")):
-        full = candidate_space(lowering)
-        quick = candidate_space(lowering, quick=True)
-        assert full[0] == TuneConfig() and quick[0] == TuneConfig()
-        assert len(set(full)) == len(full)   # deduped
-        assert len(quick) < len(full)
-        for c in full[1:]:
-            assert getattr(c, pinned) == DEFAULTS[pinned], (
-                f"{lowering} must not sweep {pinned}", c)
+    full = candidate_space()
+    quick = candidate_space(quick=True)
+    assert full[0] == TuneConfig() and quick[0] == TuneConfig()
+    assert len(set(full)) == len(full)   # deduped
+    assert len(quick) < len(full)
+    for swept in ("dma_depth", "round_leaves"):
         assert any(getattr(c, swept) != DEFAULTS[swept] for c in full)
 
 
@@ -222,15 +217,13 @@ def test_installed_nondefault_knobs_stay_bit_identical(data, tuned):
 @pytest.mark.parametrize("platform,expect", [
     ("cpu", ("mosaic", True)),               # interprets by design
     ("tpu", ("mosaic", False)),
-    ("gpu", ("triton", False)),
-    ("cuda", ("triton", False)),
-    ("rocm", ("triton", False)),
 ])
 def test_resolve_lowering_default_matrix(platform, expect):
     assert resolve_lowering(platform=platform) == expect
 
 
-@pytest.mark.parametrize("platform", ["metal", "neuron", "weird-accel"])
+@pytest.mark.parametrize("platform", ["gpu", "cuda", "rocm", "metal",
+                                      "neuron", "weird-accel"])
 def test_no_lowering_path_raises_typed_error(platform):
     for interpret in (None, False):
         with pytest.raises(KernelLoweringError) as ei:
@@ -243,17 +236,17 @@ def test_no_lowering_path_raises_typed_error(platform):
 
 
 def test_compile_mismatch_raises_typed_error():
-    # asking a platform to COMPILE a lowering it doesn't own
-    for platform, lowering in (("cpu", "triton"), ("cpu", "mosaic"),
-                               ("tpu", "triton"), ("gpu", "mosaic")):
+    # asking a platform with no compiled path to COMPILE the kernels
+    for platform in ("cpu", "gpu"):
         with pytest.raises(KernelLoweringError):
-            resolve_lowering(interpret=False, lowering=lowering,
+            resolve_lowering(interpret=False, lowering="mosaic",
                              platform=platform)
-    # but interpret mode runs either STRUCTURE anywhere, bit-identically
-    assert resolve_lowering(True, "triton", "cpu") == ("triton", True)
+    # but interpret mode runs the Mosaic structure anywhere
+    assert resolve_lowering(True, "mosaic", "cpu") == ("mosaic", True)
     assert resolve_lowering(True, "mosaic", "gpu") == ("mosaic", True)
 
 
-def test_bad_lowering_string_is_a_value_error():
+@pytest.mark.parametrize("lowering", ["triton", "cuda-graphs"])
+def test_bad_lowering_string_is_a_value_error(lowering):
     with pytest.raises(ValueError, match="lowering"):
-        resolve_lowering(lowering="cuda-graphs", platform="gpu")
+        resolve_lowering(lowering=lowering, platform="tpu")

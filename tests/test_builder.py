@@ -48,7 +48,8 @@ def reference(small):
 def test_interleaved_key_np_matches_jnp(bits, segments):
     """The numpy key mirror the builder's sort/merge phases use must be
     bit-identical to the device key, its stable lexsort must equal
-    jnp.lexsort's permutation, and the byte-packed scalar key (the merge
+    jnp.lexsort's permutation and the device build's `lexsort_lanes`
+    (ties keep input order), and the byte-packed scalar key (the merge
     path's binary-search key) must order exactly like the lane tuple.
     (Lives here, not in test_isax.py: that module skips without
     hypothesis, and these properties must run in CI.)"""
@@ -61,6 +62,8 @@ def test_interleaved_key_np_matches_jnp(bits, segments):
     lanes = [jnp.asarray(kj[:, i]) for i in range(kj.shape[1])]
     perm_j = np.asarray(jnp.lexsort(tuple(reversed(lanes))))
     np.testing.assert_array_equal(perm_j, isax.lexsort_keys(kn))
+    np.testing.assert_array_equal(
+        np.asarray(isax.lexsort_lanes(jnp.asarray(kj))), perm_j)
     packed = isax.pack_keys_bytes(kn)
     np.testing.assert_array_equal(np.argsort(packed, kind="stable"),
                                   isax.lexsort_keys(kn))

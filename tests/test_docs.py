@@ -85,7 +85,7 @@ def test_docs_exist_and_linked_from_readme():
     for knob in ("max_batch", "linger_ms", "workers", "donate",
                  "auto_compact_rows", "sync_every", "help_after_ms",
                  "latency_tiers", "recall_target",
-                 "round_leaves", "dma_depth", "block_q"):
+                 "round_leaves", "dma_depth"):
         assert knob in serving, f"SERVING.md lost the {knob} knob"
 
 

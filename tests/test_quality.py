@@ -226,10 +226,10 @@ def test_plan_key_tracks_every_knob_field():
     assert plan_key(7, Knobs()) != plan_key(7, approx)
     assert plan_key(7, Knobs()) != plan_key(8, Knobs())
     # autotune-resolved knobs are Knobs fields too, so a retune that
-    # changes dma_depth/block_q re-keys AOT plans AND the result cache
+    # changes dma_depth re-keys AOT plans AND the result cache
     names = {f.name for f in dataclasses.fields(Knobs)}
-    assert {"dma_depth", "block_q"} <= names, names
-    tuned = dataclasses.replace(Knobs(), dma_depth=2, block_q=4)
+    assert "dma_depth" in names, names
+    tuned = dataclasses.replace(Knobs(), dma_depth=2)
     assert plan_key(7, Knobs()) != plan_key(7, tuned)
 
 

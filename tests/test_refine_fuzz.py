@@ -4,8 +4,8 @@ dispatcher can take (skips cleanly when hypothesis is absent).
 
 Two layers, two contracts (see kernels/refine.py's module docstring):
 
-* kernel level — every structure (Mosaic dma_depth=1, the dma_depth>=2
-  DMA-ring, and Triton at several block_q) returns the SAME entry
+* kernel level — every structure (the pipelined dma_depth=1 kernel and
+  the dma_depth>=2 DMA ring at two depths) returns the SAME entry
   buffer bit for bit as the materializing oracle `ref.refine_topk_ref`,
   with distances within a few ULP (XLA may re-associate the oracle's
   batched einsum; the kernels accumulate in a fixed order — empirical
@@ -34,11 +34,9 @@ from repro.core import build_index, run_search, search_bruteforce  # noqa: E402
 from repro.data.synthetic import random_walk               # noqa: E402
 from repro.kernels import ops, ref                         # noqa: E402
 
-# (lowering, dma_depth, block_q): all three kernel structures, the ring
-# at two depths and Triton at three block widths — every combination the
-# autotune sweep can propose
-STRUCTURES = (("mosaic", 1, 1), ("mosaic", 2, 1), ("mosaic", 4, 1),
-              ("triton", 1, 1), ("triton", 1, 2), ("triton", 1, 4))
+# dma_depth: the pipelined kernel (1) and the DMA ring at two depths —
+# every structure the autotune sweep can propose
+STRUCTURES = (1, 2, 4)
 
 # sampled (not drawn free-form) so jit caches are shared across examples
 # and the 50+ cases stay fast in interpret mode.  Each example draws ONE
@@ -98,17 +96,16 @@ def test_every_structure_matches_the_oracle(Q, K, M, NL, L, k, dtype,
     dr, er = ref.refine_topk_ref(q, qsq, stored, sqn, ids, alive,
                                  bsf_d, bsf_e, leaf_capacity=M, k=k)
     dr, er = np.asarray(dr), np.asarray(er)
-    lowering, dd, bq = structure
+    dd = structure
     dk, ek = ops.refine_topk(q, qsq, stored, sqn, ids, alive,
                              bsf_d, bsf_e, leaf_capacity=M, k=k,
-                             interpret=True, lowering=lowering,
-                             dma_depth=dd, block_q=bq)
+                             interpret=True, dma_depth=dd)
     np.testing.assert_array_equal(np.asarray(ek), er, err_msg=str(
-        ("entry buffer mismatch", lowering, dd, bq,
+        ("entry buffer mismatch", dd,
          Q, K, M, NL, L, k, dtype, alive_mode, seed)))
     ulp = _ulp_diff(dk, dr)
     assert ulp.max(initial=0) <= 8, (
-        "distance beyond 8 ULP of the oracle", lowering, dd, bq,
+        "distance beyond 8 ULP of the oracle", dd,
         int(ulp.max()), Q, K, M, NL, L, k, dtype, alive_mode, seed)
 
 
@@ -119,7 +116,7 @@ def test_every_structure_matches_the_oracle(Q, K, M, NL, L, k, dtype,
 def test_structures_agree_on_the_carried_buffer(Q, k, alive_mode, seed):
     """Two chained rounds (the second folds into a non-trivial carry):
     every structure must thread the SAME buffer through both.  Shape
-    axes beyond (Q, k) are pinned — this test DOES loop all six
+    axes beyond (Q, k) are pinned — this test DOES loop all three
     structures per example, so its jit-key budget is kept small."""
     K, NL, M, L = 3, 6, 8, 32
     q, qsq, stored, _, sqn, ids, alive, bsf_d, bsf_e = _case(
@@ -127,23 +124,20 @@ def test_structures_agree_on_the_carried_buffer(Q, k, alive_mode, seed):
     ids2 = jnp.asarray(
         np.random.default_rng(seed + 1).integers(0, NL, (Q, K)), jnp.int32)
     outs = []
-    for lowering, dd, bq in STRUCTURES:
+    for dd in STRUCTURES:
         d1, e1 = ops.refine_topk(q, qsq, stored, sqn, ids, alive,
                                  bsf_d, bsf_e, leaf_capacity=M, k=k,
-                                 interpret=True, lowering=lowering,
-                                 dma_depth=dd, block_q=bq)
+                                 interpret=True, dma_depth=dd)
         d2, e2 = ops.refine_topk(q, qsq, stored, sqn, ids2,
                                  jnp.ones_like(alive), d1, e1,
                                  leaf_capacity=M, k=k, interpret=True,
-                                 lowering=lowering, dma_depth=dd,
-                                 block_q=bq)
-        outs.append((lowering, dd, bq, np.asarray(d2), np.asarray(e2)))
-    _, _, _, d0, e0 = outs[0]
-    for lowering, dd, bq, d, e in outs[1:]:
+                                 dma_depth=dd)
+        outs.append((dd, np.asarray(d2), np.asarray(e2)))
+    _, d0, e0 = outs[0]
+    for dd, d, e in outs[1:]:
         np.testing.assert_array_equal(e, e0, err_msg=str(
-            ("chained entries diverged", lowering, dd, bq, seed)))
-        assert _ulp_diff(d, d0).max(initial=0) <= 8, (
-            lowering, dd, bq, seed)
+            ("chained entries diverged", dd, seed)))
+        assert _ulp_diff(d, d0).max(initial=0) <= 8, (dd, seed)
 
 
 @settings(max_examples=12, deadline=None,

@@ -3,7 +3,8 @@
 The whole §Roofline analysis rests on this parser, so it gets its own
 oracle tests: exact dot FLOPs, while-loop trip multiplication (XLA's own
 cost_analysis counts loop bodies once — verified here), and collective
-byte extraction in a multi-device subprocess.
+byte extraction in a multi-device subprocess.  The peaks table behind
+roofline fractions is keyed by exact device kind and refuses to guess.
 """
 
 import os
@@ -13,8 +14,9 @@ import textwrap
 
 import jax
 import jax.numpy as jnp
+import pytest
 
-from repro.launch.roofline import analyze_hlo
+from repro.launch.roofline import analyze_hlo, device_peaks, roofline_fraction
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -109,3 +111,16 @@ def test_bytes_hbm_reasonable_for_matmul():
     cost = analyze_hlo(hlo)
     ideal = 3 * 512 * 512 * 4       # read a, b; write c
     assert ideal <= cost.bytes_hbm <= 3 * ideal, cost.bytes_hbm
+
+
+def test_v5e_peaks_are_the_published_ones():
+    assert device_peaks("TPU v5 lite") == (197e12, 819e9)
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "tpu",
+                                  "NVIDIA H100", "weird-accel"])
+def test_unknown_device_kind_raises(kind):
+    with pytest.raises(KeyError, match="no published peaks"):
+        device_peaks(kind)
+    with pytest.raises(KeyError):
+        roofline_fraction(1e-3, Q=1, K=1, M=8, L=32, k=1, kind=kind)
