@@ -1,5 +1,4 @@
-"""Refine-kernel autotune + roofline bench (`benchmarks/run.py
---autotune-quick`).
+"""Refine-kernel autotune bench (`benchmarks/run.py --autotune-quick`).
 
 Emits the backend-tuning rows next to the figure rows in
 BENCH_fresh.json:
@@ -14,13 +13,6 @@ BENCH_fresh.json:
 * ``kernels/refine/autotune/table``    — proof of the table write: the
   AutotuneTable is persisted as JSON under results/ and the row records
   its path, entry count and content fingerprint.
-* ``kernels/refine/roofline_frac``     — one fused refine round timed
-  directly through `ops.refine_topk` and divided into the analytic
-  roofline bound (`launch.roofline.roofline_fraction`): the
-  "fast as the hardware allows" regression number.  On CPU the kernel
-  interprets, so the fraction is a tiny correctness-trace value —
-  smoke.sh gates it as present and > 0; on real accelerators the same
-  row becomes a meaningful %-of-peak.
 """
 
 from __future__ import annotations
@@ -29,12 +21,8 @@ import os
 import time
 from typing import List
 
-import numpy as np
-
 from repro.api import FreshIndex, IndexConfig
 from repro.data.synthetic import query_workload, random_walk
-from repro.kernels.autotune import device_kind
-from repro.launch.roofline import device_peaks, roofline_fraction
 
 from .common import row
 
@@ -48,67 +36,20 @@ N_QUERIES = 32
 REPEAT = 5
 QUICK = False
 
-# the directly-timed roofline round (kernel-level, no PQ/round loop)
-ROOF_Q, ROOF_K, ROOF_ROUNDS = 32, 8, 20
-
 
 def set_quick() -> None:
     """CI smoke scale: smaller index + two-point autotune grids.  The
-    rows' claims (table written, winner bit-exact, roofline_frac > 0)
-    are scale-independent; only the timings shrink."""
-    global N_SERIES, N_QUERIES, REPEAT, QUICK, ROOF_ROUNDS
+    rows' claims (table written, winner bit-exact) are
+    scale-independent; only the timings shrink."""
+    global N_SERIES, N_QUERIES, REPEAT, QUICK
     N_SERIES = 2_048
     N_QUERIES = 16
     REPEAT = 3
     QUICK = True
-    ROOF_ROUNDS = 10
-
-
-def _roofline_row() -> dict:
-    """Time ONE fused refine round through ops.refine_topk and report
-    the achieved fraction of the analytic roofline bound."""
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    k = 10
-    M, L = LEAF_CAPACITY, SERIES_LEN
-    n_leaves = max(ROOF_K, N_SERIES // M)
-    rng = np.random.default_rng(7)
-    series = jnp.asarray(rng.standard_normal((n_leaves * M, L)),
-                         jnp.float32)
-    sq_norms = jnp.sum(series * series, axis=-1).reshape(n_leaves, M)
-    q = jnp.asarray(rng.standard_normal((ROOF_Q, L)), jnp.float32)
-    q_sq = jnp.sum(q * q, axis=-1)
-    ids = jnp.asarray(
-        rng.integers(0, n_leaves, (ROOF_Q, ROOF_K)), jnp.int32)
-    alive = jnp.ones((ROOF_Q, ROOF_K), jnp.bool_)
-    bsf_d = jnp.full((ROOF_Q, k), 3.4e38, jnp.float32)
-    bsf_e = jnp.zeros((ROOF_Q, k), jnp.int32)
-
-    def run():
-        return ops.refine_topk(q, q_sq, series, sq_norms, ids, alive,
-                               bsf_d, bsf_e, leaf_capacity=M, k=k)
-
-    d, _ = run()
-    d.block_until_ready()                       # compile outside the clock
-    t0 = time.perf_counter()
-    for _ in range(ROOF_ROUNDS):
-        d, _ = run()
-    d.block_until_ready()
-    per_round = (time.perf_counter() - t0) / ROOF_ROUNDS
-
-    frac = roofline_fraction(per_round, Q=ROOF_Q, K=ROOF_K, M=M, L=L, k=k)
-    peak_flops, hbm_bw = device_peaks()
-    return row("kernels/refine/roofline_frac", per_round,
-               derived=(f"Q={ROOF_Q} K={ROOF_K} M={M} L={L} "
-                        f"device={device_kind()} "
-                        f"peaks={peak_flops:.0e}F/{hbm_bw:.0e}B"),
-               roofline_frac=float(f"{frac:.4g}"))
 
 
 def kernels_refine_autotune() -> List[dict]:
-    """The autotune sweep + table write + roofline fraction, as rows."""
+    """The autotune sweep + table write, as rows."""
     walks = random_walk(N_SERIES, SERIES_LEN, seed=71)
     queries = query_workload(walks, N_QUERIES, noise_sigma=0.05, seed=72)
     ix = FreshIndex.build(
@@ -138,7 +79,6 @@ def kernels_refine_autotune() -> List[dict]:
             derived=(f"entries={len(table)} device={key[0]} "
                      f"fingerprint={table.fingerprint[:12]}"),
             path=os.path.relpath(path, os.path.dirname(RESULTS))),
-        _roofline_row(),
     ]
     return rows
 

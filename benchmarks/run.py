@@ -49,8 +49,7 @@ def main() -> None:
     ap.add_argument("--autotune-quick", action="store_true",
                     help="also run the refine-kernel autotune sweep "
                          "(kernels/* rows: bitwise-gated winner vs "
-                         "baseline, AutotuneTable write, and the "
-                         "asserted kernels/refine/roofline_frac row)")
+                         "baseline and the AutotuneTable write)")
     args = ap.parse_args()
 
     from repro.launch.compile_cache import use_compile_cache

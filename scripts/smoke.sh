@@ -14,9 +14,8 @@
 # cost) AND the recall-tiered approximate-search leg (--quality-quick:
 # calibrated recall@k >= target, approx p99 < exact p99 on one
 # latency-tiered engine) AND the refine-kernel autotune leg
-# (--autotune-quick: tiny bitwise-gated sweep on the live device,
-# AutotuneTable JSON write, and the asserted
-# kernels/refine/roofline_frac row, present and > 0) at --quick scale,
+# (--autotune-quick: tiny bitwise-gated sweep on the live device and
+# the AutotuneTable JSON write) at --quick scale,
 # emitting the machine-readable BENCH_fresh.json perf record with
 # p50/p99 latency + QPS rows.
 #
@@ -71,8 +70,8 @@ EOF
 validate_autotune_rows() {
     # $1: the bench JSON to check (defaults to the committed record).
     # Asserts the kernels/* rows exist, the sweep's winner survived the
-    # bitwise exactness gate, the AutotuneTable JSON was written
-    # non-empty, and roofline_frac is present and strictly positive.
+    # bitwise exactness gate, and the AutotuneTable JSON was written
+    # non-empty.
     BENCH_JSON="${1:-BENCH_fresh.json}" python - <<'EOF'
 import json
 import os
@@ -82,8 +81,7 @@ rows = json.load(open(path))["rows"]
 by_name = {r["name"]: r for r in rows}
 for name in ("kernels/refine/autotune/baseline",
              "kernels/refine/autotune/winner",
-             "kernels/refine/autotune/table",
-             "kernels/refine/roofline_frac"):
+             "kernels/refine/autotune/table"):
     assert name in by_name, f"missing {name} row in {path}"
 win = by_name["kernels/refine/autotune/winner"]
 assert 1 <= win["n_exact"] <= win["n_candidates"], (
@@ -95,11 +93,9 @@ assert os.path.exists(table_path), (
 table = json.load(open(table_path))
 assert table.get("entries"), ("autotune table written empty", table_path)
 assert table.get("fingerprint"), ("table missing fingerprint", table_path)
-frac = by_name["kernels/refine/roofline_frac"]["roofline_frac"]
-assert frac > 0, ("roofline_frac must be strictly positive", frac)
 print(f"kernels/* rows OK (winner speedup={win['speedup']}x, "
       f"{win['n_exact']}/{win['n_candidates']} candidates bit-exact, "
-      f"roofline_frac={frac}, table={table_path} "
+      f"table={table_path} "
       f"entries={len(table['entries'])})")
 EOF
 }
@@ -107,7 +103,7 @@ EOF
 run_autotune_quick() {
     # tiny sweep on the live device to a scratch JSON (doesn't clobber
     # the committed BENCH_fresh.json): exercises the bitwise gate, the
-    # AutotuneTable write and the roofline_frac row end to end
+    # AutotuneTable write end to end
     python -m benchmarks.run --only kernels --quick --autotune-quick \
         --json /tmp/bench_autotune.json
     validate_autotune_rows /tmp/bench_autotune.json

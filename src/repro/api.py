@@ -95,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analysis.hooks import observe
 from repro.checkpoint.store import load_arrays, save_checkpoint
 from repro.core import isax
@@ -102,7 +103,7 @@ from repro.core.builder import IndexBuilder, merge_sorted_delta
 from repro.core.index import (FlatIndex, build_index, index_stats,
                               pad_leaves)
 from repro.core.search import (build_sharded_search, merge_delta_topk,
-                               run_search, shard_index, squeeze_k)
+                               search_plan, shard_index, squeeze_k)
 from repro.maintenance.tombstones import (core_dead_mask, delta_alive_mask,
                                           mask_core)
 from repro.quality.calibrate import CalibrationTable, index_fingerprint
@@ -371,6 +372,15 @@ class FreshIndex:
         Concurrency: read-only; may observe a concurrent writer's
         intermediate delta count — serialize externally if you need a
         consistent cut (the serving engine does).
+
+        What the searches did is kept apart, process-wide (it outlives
+        the index): `repro.obs.totals()` sums the last
+        `repro.obs.CAPACITY` searches through this facade — searches,
+        queries, rounds, live query-rounds (a query is live in a round
+        when its next unrefined lower bound beats its k-th best so far)
+        and refined (query, leaf) pairs; `repro.obs.records()` holds
+        them call by call.  A sharded index's calls count searches and
+        queries only.
         """
         st = index_stats(self._idx)
         st["n_pending"] = self.n_pending
@@ -435,6 +445,58 @@ class FreshIndex:
         writer (add/compact) has NO defined ordering on this facade —
         use `engine()` for snapshot-consistent concurrent add/search.
         """
+        with jax.profiler.TraceAnnotation(obs.SEARCH_SPAN):
+            with jax.profiler.TraceAnnotation(obs.PREPARE_SPAN):
+                q, rule, rl, pqb, bk, dd = self._search_args(
+                    queries, k, mode, recall_target, stop_eps, max_leaves,
+                    round_leaves, pq_budget, backend)
+                core, delta, alive, id0 = self.search_view()
+            if self._mesh is not None:
+                # the mesh placement is part of the key (not just cleared
+                # on shard()): a compiled shard_map search can never be
+                # replayed against arrays living on a different placement
+                key = (k, rl, sync_every, max_rounds, pqb,
+                       bk, dd, rule, mesh_sig(self._mesh))
+                fn = self._sharded_fns.get(key)
+                if fn is None:
+                    fn = build_sharded_search(
+                        self._mesh, axis=self._mesh_axis, k=k,
+                        round_leaves=rl, sync_every=sync_every,
+                        max_rounds=max_rounds, znorm=self.config.znorm,
+                        pq_budget=pqb, backend=bk,
+                        dma_depth=dd, config=self.config, **rule.lower())
+                    self._sharded_fns[key] = fn
+                d, i = fn(core, q)
+                counts = None             # the sharded plan counts nothing
+            else:
+                # the knobs are resolved: dispatch the jitted plan
+                d, i, counts = search_plan(
+                    core, q, k=k, round_leaves=rl, znorm=self.config.znorm,
+                    max_rounds=max_rounds, pq_budget=pqb, backend=bk,
+                    dma_depth=dd, **rule.lower())
+                d, i = squeeze_k(d, i, k)
+            if delta is not None:
+                # fold the exact delta scan into the core answer.  The
+                # core search program stays cached across add() calls;
+                # only the small merge re-jits when the delta row count
+                # changes.  (The serving layer instead AOT-compiles the
+                # fused snapshot_search once per published epoch — same
+                # math, different compile amortization.)
+                d2 = d[:, None] if k == 1 else d
+                i2 = i[:, None] if k == 1 else i
+                md, mi = merge_delta_topk(delta, q, d2, i2, alive, k=k,
+                                          n_base=id0,
+                                          znorm=self.config.znorm)
+                d, i = squeeze_k(md, mi, k)
+            if self._alias:
+                i = jnp.asarray(self._remap_ids(np.asarray(i)))
+            obs.record(q.shape[0], rl, counts)
+            return d, i
+
+    def _search_args(self, queries, k, mode, recall_target, stop_eps,
+                     max_leaves, round_leaves, pq_budget, backend):
+        """`search`'s checked queries, stop rule and knobs: (q, rule,
+        round_leaves, pq_budget, backend, dma_depth)."""
         q = jnp.asarray(queries, jnp.float32)
         if q.ndim == 1:
             q = q[None]
@@ -452,52 +514,14 @@ class FreshIndex:
                                       max_leaves=max_leaves)
         # resolve every search knob NOW (explicit arg > IndexConfig >
         # fresh autotune table > static default) so the compiled-search
-        # cache below keys on VALUES — a retuned table changes the key,
-        # never silently re-resolves under a stale compiled fn
+        # cache keys on VALUES — a retuned table changes the key, never
+        # silently re-resolves under a stale compiled fn
         kn = self.search_knobs()
         rl = round_leaves if round_leaves is not None else kn.round_leaves
         pqb = pq_budget if pq_budget is not None else kn.pq_budget
         bk = backend if backend is not None else self.config.backend
         dd = kn.dma_depth if bk == "pallas" else 1
-        core, delta, alive, id0 = self.search_view()
-        if self._mesh is not None:
-            # the mesh placement is part of the key (not just cleared on
-            # shard()): a compiled shard_map search can never be replayed
-            # against arrays living on a different placement
-            key = (k, rl, sync_every, max_rounds, pqb,
-                   bk, dd, rule, mesh_sig(self._mesh))
-            fn = self._sharded_fns.get(key)
-            if fn is None:
-                fn = build_sharded_search(
-                    self._mesh, axis=self._mesh_axis, k=k,
-                    round_leaves=rl, sync_every=sync_every,
-                    max_rounds=max_rounds, znorm=self.config.znorm,
-                    pq_budget=pqb, backend=bk,
-                    dma_depth=dd, config=self.config, **rule.lower())
-                self._sharded_fns[key] = fn
-            d, i = fn(core, q)
-        else:
-            d, i = run_search(core, q, k=k, round_leaves=rl,
-                              znorm=self.config.znorm,
-                              max_rounds=max_rounds, pq_budget=pqb,
-                              backend=bk, dma_depth=dd,
-                              config=self.config,
-                              **rule.lower())
-        if delta is not None:
-            # fold the exact delta scan into the core answer.  The core
-            # search program stays cached across add() calls; only the
-            # small merge re-jits when the delta row count changes.  (The
-            # serving layer instead AOT-compiles the fused
-            # snapshot_search once per published epoch — same math,
-            # different compile amortization.)
-            d2 = d[:, None] if k == 1 else d
-            i2 = i[:, None] if k == 1 else i
-            md, mi = merge_delta_topk(delta, q, d2, i2, alive, k=k,
-                                      n_base=id0, znorm=self.config.znorm)
-            d, i = squeeze_k(md, mi, k)
-        if self._alias:
-            i = jnp.asarray(self._remap_ids(np.asarray(i)))
-        return d, i
+        return q, rule, rl, pqb, bk, dd
 
     def resolve_stop_rule(self, mode: str, *, k: int,
                           recall_target: float = 0.95,

@@ -5,7 +5,8 @@ knob resolution and dispatch so the facade and the serving layer share
 one code path —
 
   search_plan_impl   the pure plan: fully-resolved knobs, (Q, k) outputs
-                     plus the refinement-round count; traceable, no jit
+                     plus the loop's counters (`COUNTERS`); traceable,
+                     no jit
   search_plan        jax.jit(search_plan_impl) — what FreshIndex.search
                      dispatches through and what serve.PlanCache
                      AOT-compiles per (bucket, k) with .lower().compile()
@@ -75,6 +76,11 @@ from . import isax
 from .index import FlatIndex
 
 BIG = jnp.float32(1e30)
+
+#: what the single-device plan's counter output holds, in order:
+#: refinement rounds; (query, round) pairs in which the query was live;
+#: (query, leaf) pairs whose distances the refine round computed
+COUNTERS = ("rounds", "live_query_rounds", "refined_pairs")
 
 
 _BACKENDS = ("ref", "pallas")
@@ -318,10 +324,16 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     bit-identical on the same snapshot.  No knob resolution, no squeezing,
     no dispatch happens here; callers pass concrete values.
 
-    Returns (dist, original_id, rounds): dist/id are (Q, k) ascending by
-    distance (no k == 1 squeeze — see `run_search`), rounds is the scalar
-    number of refinement rounds the while_loop executed (the paper's
-    DeleteMin count; the serving layer surfaces it as rounds-per-query).
+    Returns (dist, original_id, counts): dist/id are (Q, k) ascending by
+    distance (no k == 1 squeeze — see `run_search`); counts is the (3,)
+    int32 array of the loop's own counters, in `COUNTERS` order:
+    the refinement rounds the while_loop executed (the paper's DeleteMin
+    count; the serving layer surfaces it as rounds-per-query), the
+    (query, round) pairs in which the query was still live (its next
+    unrefined lower bound beat its k-th best-so-far), and the (query,
+    leaf) pairs the refine round computed distances for (`alive`; dead
+    slots skip both their copy and their arithmetic).  The counts stay
+    on the device; reading them is the caller's choice.
 
     The BSF scalar of the paper generalizes to a per-query top-k buffer:
     each refinement round's real distances are folded in with
@@ -366,7 +378,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     order, sorted_lb = _pq_order(lb, K, n_rounds_cap, leaf_budget)
 
     def cond(state):
-        cursor, bsf_d, _ = state
+        cursor, bsf_d = state[:2]
         # PQ termination: stop when the best unrefined lb >= the k-th BSF
         # (scaled by 1/(1+eps)^2 in approx mode: no remaining candidate
         # can improve the k-th answer by more than the (1+eps) factor)
@@ -376,7 +388,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
         return jnp.logical_and(cursor < n_rounds_cap * K, live)
 
     def body(state):
-        cursor, bsf_d, bsf_e = state
+        cursor, bsf_d, bsf_e, live, refined = state
         ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
         lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
         # prune: leaves whose lb >= the current k-th BSF contribute
@@ -387,11 +399,15 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
                                      ids, alive, bsf_d, bsf_e,
                                      M=M, k=k, backend=backend,
                                      dma_depth=dma_depth)
-        return cursor + K, bsf_d, bsf_e
+        # the PQ is sorted, so slot 0 says whether the query is live
+        live = live + jnp.sum(alive[:, 0], dtype=jnp.int32)
+        refined = refined + jnp.sum(alive, dtype=jnp.int32)
+        return cursor + K, bsf_d, bsf_e, live, refined
 
     state = (jnp.int32(0), jnp.full((Q, k), BIG),
-             jnp.zeros((Q, k), jnp.int32))
-    cursor, bsf_d, bsf_e = jax.lax.while_loop(cond, body, state)
+             jnp.zeros((Q, k), jnp.int32), jnp.int32(0), jnp.int32(0))
+    cursor, bsf_d, bsf_e, live, refined = jax.lax.while_loop(cond, body,
+                                                             state)
 
     # the top-k set is exact; the matmul-form distance loses ~1e-3 absolute
     # to f32 cancellation (||q||^2+||x||^2-2qx with ||.||^2 ~ L).  Recompute
@@ -403,7 +419,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     resort = jnp.argsort(d, axis=1)
     d = jnp.sqrt(jnp.take_along_axis(d, resort, axis=1))
     ids = jnp.take_along_axis(ids, resort, axis=1)
-    return d, ids, cursor // K
+    return d, ids, jnp.stack([cursor // K, live, refined])
 
 
 search_plan = functools.partial(
@@ -499,9 +515,9 @@ def snapshot_search_impl(idx: FlatIndex, delta: jnp.ndarray,
     `stop_eps` / `stop_leaves` apply to the CORE plan only (see
     `search_plan_impl`): the delta scan stays exact — it is one matmul
     over the (small) pending buffer, so skipping any of it would trade
-    recall for nothing.
+    recall for nothing.  The counter output is the core plan's.
     """
-    d, i, rounds = search_plan_impl(
+    d, i, counts = search_plan_impl(
         idx, queries, k=k, round_leaves=round_leaves, znorm=znorm,
         max_rounds=max_rounds, backend=backend, pq_budget=pq_budget,
         stop_eps=stop_eps, stop_leaves=stop_leaves,
@@ -511,7 +527,7 @@ def snapshot_search_impl(idx: FlatIndex, delta: jnp.ndarray,
                               alive=delta_alive)
     md, mi = _merge_topk(d, i, dd,
                          _shift_delta_ids(di, n_base, delta_alive), k)
-    return md, mi, rounds
+    return md, mi, counts
 
 
 snapshot_search = functools.partial(

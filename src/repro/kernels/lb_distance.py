@@ -28,6 +28,10 @@ from jax.experimental import pallas as pl
 
 from repro.core import isax
 
+#: the kernel's name in the compiled program and a device trace
+#: (`%lb_distance.<n>`), whatever the enclosing Python function is named
+KERNEL_NAME = "lb_distance"
+
 
 def _lb_kernel(q_ref, lo_ref, hi_ref, out_ref, *, scale: float):
     w = q_ref.shape[1]        # q (BQ, w); lo/hi (w, BL)
@@ -82,6 +86,7 @@ def lb_distance(q_paa: jnp.ndarray, leaf_lo: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bq, bl), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Qp, NLp), jnp.float32),
+        name=KERNEL_NAME,
         interpret=interpret,
     )(q_paa, leaf_lo, leaf_hi)
     return out[:Q, :NL]

@@ -101,6 +101,11 @@ from ._compat import resolve_lowering
 
 BIG = 1e30
 
+#: the name both structures give their Mosaic kernel, so the compiled
+#: program (and a device trace) calls it `%refine_topk.<n>` whatever the
+#: enclosing Python function is named
+KERNEL_NAME = "refine_topk"
+
 
 def _rank_select(u_d: jnp.ndarray, u_e: jnp.ndarray, kp: int
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -268,6 +273,7 @@ def _refine_mosaic(q, q_sq, series, sq_norms, ids32, alive32, bsf_d, bsf_e,
     out_d, out_e = pl.pallas_call(
         functools.partial(_refine_kernel, leaf_capacity=M, kp=kp),
         grid_spec=grid_spec,
+        name=KERNEL_NAME,
         out_shape=[
             jax.ShapeDtypeStruct((Q, 1, kp), jnp.float32),
             jax.ShapeDtypeStruct((Q, 1, kp), jnp.int32),
@@ -318,6 +324,7 @@ def _refine_mosaic_dma(q, q_sq, series, sq_norms, ids32, alive32, bsf_d,
         functools.partial(_refine_kernel_dma, leaf_capacity=M, kp=kp,
                           depth=depth, n_slots=K),
         grid_spec=grid_spec,
+        name=KERNEL_NAME,
         out_shape=[
             jax.ShapeDtypeStruct((Q, 1, kp), jnp.float32),
             jax.ShapeDtypeStruct((Q, 1, kp), jnp.int32),

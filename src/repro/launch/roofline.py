@@ -280,18 +280,14 @@ def model_flops_for(cfg, shape) -> float:
 
 
 # ---------------------------------------------------------------------- #
-# refine-kernel roofline: the asserted %-of-roofline bench number
+# device peaks and the refine round's analytic cost
 # ---------------------------------------------------------------------- #
 #: (peak_flops, hbm_bytes_per_s) per `jax.devices()[0].device_kind`,
 #: matched exactly.  A kind missing here is an error, never a default.
 #:   "TPU v5 lite" (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM — Google
 #:       Cloud documentation, "TPU v5e".
-#:   "cpu": a nominal server figure, not a published peak — on CPU the
-#:       kernels run in interpret mode, so a roofline fraction there is
-#:       a correctness trace, never a device metric.
 DEVICE_PEAKS = {
     "TPU v5 lite": (197e12, 819e9),
-    "cpu": (2.0e11, 5.0e10),
 }
 
 
@@ -316,7 +312,7 @@ def refine_analytic(Q: int, K: int, M: int, L: int, k: int,
     fused kernel (each (M, L) leaf block streamed exactly once) and the
     materializing ref path (gather written out + read back + source).
     The single source of truth behind `benchmarks.roofline_table.
-    refine_rows` and the `kernels/refine/roofline_frac` bench row."""
+    refine_rows`."""
     flops = 2.0 * Q * K * M * L
     leaf = float(dtype_bytes) * Q * K * M * L     # gathered member rows
     small = 4.0 * Q * L + 12.0 * Q * k            # queries + BSF buffers
@@ -324,18 +320,3 @@ def refine_analytic(Q: int, K: int, M: int, L: int, k: int,
             "bytes_fused": leaf + small,
             "bytes_mat": 3.0 * leaf + small}
 
-
-def roofline_fraction(seconds: float, *, Q: int, K: int, M: int, L: int,
-                      k: int, dtype_bytes: int = 4,
-                      kind: Optional[str] = None) -> float:
-    """Fraction of the hardware roofline one measured refine round hit:
-    `max(t_compute, t_memory) / seconds` with the fused-path analytic
-    terms over `device_peaks(kind)`.  1.0 = the round ran exactly as
-    fast as the dominant roofline term allows; interpret-mode CPU
-    traces land orders of magnitude below (documented, not clamped)."""
-    if seconds <= 0:
-        raise ValueError(f"seconds must be > 0, got {seconds}")
-    peak_flops, hbm_bw = device_peaks(kind)
-    a = refine_analytic(Q, K, M, L, k, dtype_bytes)
-    bound = max(a["flops"] / peak_flops, a["bytes_fused"] / hbm_bw)
-    return bound / seconds

@@ -324,7 +324,7 @@ def _run_setting(index, q, k: int, rule: StopRule, backend: Optional[str],
             return search_plan(core, qj, **kw)
         return snapshot_search(core, delta, qj, alive, n_base=id0, **kw)
 
-    d, i, rounds = run()                    # warmup (compile) + answers
+    d, i, counts = run()                    # warmup (compile) + answers
     d.block_until_ready()
     ts = []
     for _ in range(max(1, repeat)):
@@ -337,7 +337,7 @@ def _run_setting(index, q, k: int, rule: StopRule, backend: Optional[str],
     for cap in (kn.pq_budget, rule.max_leaves):
         if cap is not None:
             budget = min(budget, cap)
-    visited = min(int(rounds) * K, budget)
+    visited = min(int(counts[0]) * K, budget)       # counts[0]: rounds
     return (index._remap_ids(np.asarray(i, np.int32)), visited,
             ts[len(ts) // 2])
 

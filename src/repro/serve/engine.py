@@ -486,6 +486,9 @@ class QueryEngine:
         self._latencies: deque = deque(maxlen=cfg.latency_window)
         self._rounds_sum = 0.0
         self._rounds_n = 0
+        self._live_query_rounds = 0         # summed over dispatched rows
+        self._refined_pairs = 0
+        self._counted_rows = 0
         self._completed = 0
         self._dispatched = 0
         self._padded_slots = 0
@@ -1261,10 +1264,15 @@ class QueryEngine:
         knobs = batch.knobs if batch.knobs is not None else self._knobs
         plan = self.plans.get(snap, batch.queries.shape[0], batch.k,
                               knobs)
-        d, i, rounds = plan.run(snap, jnp.asarray(batch.queries))
+        d, i, counts = plan.run(snap, jnp.asarray(batch.queries))
         d = np.asarray(d)
         i = np.asarray(i)
-        rounds = int(rounds)
+        # the local plans count (rounds, live query-rounds, refined
+        # pairs) over the bucket's rows, pad rows included; the sharded
+        # plan counts its rounds alone
+        counts = np.atleast_1d(np.asarray(counts))
+        rounds = int(counts[0])
+        counted = counts.shape[0] == 3
         if snap.id_alias:
             # rows renamed by update() answer under their stable public
             # id; the remap uses the alias view frozen at this batch's
@@ -1272,16 +1280,13 @@ class QueryEngine:
             i = i.copy()
             for internal, stable in snap.id_alias:
                 i[i == internal] = stable
-        # visited-leaf accounting for the quality tier counters: the
-        # round loop refines round_leaves per round, capped by the PQ
-        # budget and the tier's stop_leaves
-        budget = exact_budget = int(snap.core.n_leaves)
+        # a tier stops early where its loop ended before reaching the
+        # leaves an exact search may take
+        exact_budget = int(snap.core.n_leaves)
         if knobs.pq_budget is not None:
-            budget = exact_budget = min(budget, knobs.pq_budget)
-        if knobs.stop_leaves is not None:
-            budget = min(budget, knobs.stop_leaves)
-        visited = min(rounds * knobs.round_leaves, budget)
-        early_stop = batch.tier != "exact" and visited < exact_budget
+            exact_budget = min(exact_budget, knobs.pq_budget)
+        early_stop = (batch.tier != "exact"
+                      and rounds * knobs.round_leaves < exact_budget)
         # fingerprint the real query rows OUTSIDE the locks — hashing is
         # the only non-O(1) part of the cache fill below
         fps = None
@@ -1300,8 +1305,15 @@ class QueryEngine:
             tstats = self._tier_note(batch.tier)
             tstats["queries"] += batch.n_real
             tstats["batches"] += 1
-            tstats["visited_leaves"] += visited * batch.n_real
-            tstats["visited_n"] += batch.n_real
+            if counted:
+                self._live_query_rounds += int(counts[1])
+                self._refined_pairs += int(counts[2])
+                self._counted_rows += batch.queries.shape[0]
+                # leaves refined per query: the bucket's pairs over its
+                # rows (pad rows repeat the last real query)
+                tstats["visited_leaves"] += (int(counts[2]) * batch.n_real
+                                             / batch.queries.shape[0])
+                tstats["visited_n"] += batch.n_real
             if early_stop:
                 tstats["early_stops"] += batch.n_real
             for fut, dst, src, n in batch.segments:
@@ -1476,6 +1488,10 @@ class QueryEngine:
                 },
                 "rounds_per_query": (self._rounds_sum / self._rounds_n
                                      if self._rounds_n else 0.0),
+                "rounds_sum": self._rounds_sum,
+                "live_query_rounds": self._live_query_rounds,
+                "refined_pairs": self._refined_pairs,
+                "counted_rows": self._counted_rows,
                 "maintenance": {
                     "policy": (None if self._policy is None
                                else self._policy.freshness.name),

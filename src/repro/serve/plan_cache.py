@@ -98,7 +98,8 @@ def plan_key(k: int, knobs: Knobs) -> tuple:
 
 class CompiledPlan:
     """One AOT-compiled executable: fixed (bucket_Q, k, knobs, snapshot
-    shape).  `run(snapshot, queries)` -> (dist (Q, k), ids (Q, k), rounds).
+    shape).  `run(snapshot, queries)` -> (dist (Q, k), ids (Q, k),
+    counts): the plan's (3,) counter array (`core.search.COUNTERS`).
 
     `has_alive` mirrors the snapshot's tombstone state: epochs whose
     delta carries an alive mask compile (and run) the masked program —
@@ -136,7 +137,8 @@ class ShardedCompiledPlan:
     """One AOT-compiled MESH executable pair for a sharded snapshot.
 
     `core` is the compiled `build_sharded_plan` program (shard_map over
-    the mesh; returns (Q, k) dist/ids plus the replicated round count);
+    the mesh; returns (Q, k) dist/ids plus the replicated round count,
+    a scalar: the sharded plan counts nothing else);
     `merge` (present only for delta-carrying epochs) is the compiled
     `merge_delta_topk` that folds the exact scan of the snapshot's delta
     into the core answer — the SAME two-program split the sharded facade

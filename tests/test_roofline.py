@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.roofline import analyze_hlo, device_peaks, roofline_fraction
+from repro.launch.roofline import analyze_hlo, device_peaks
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -122,5 +122,3 @@ def test_v5e_peaks_are_the_published_ones():
 def test_unknown_device_kind_raises(kind):
     with pytest.raises(KeyError, match="no published peaks"):
         device_peaks(kind)
-    with pytest.raises(KeyError):
-        roofline_fraction(1e-3, Q=1, K=1, M=8, L=32, k=1, kind=kind)

@@ -121,3 +121,58 @@ def test_ed_argmin_compiles(compile_tpu, spec):
     from repro.kernels.ed_argmin import ed_argmin
     compile_tpu(lambda q, xs: ed_argmin(q, xs, interpret=False),
                 spec((128, L)), spec((4096, L)))
+
+
+
+def _metric_pattern(name):
+    """The op-name pattern by which a benchmark metric reads a kernel's
+    device time (`bench/metrics/<name>.py`)."""
+    import importlib.util
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    path = os.path.join(root, "bench", "metrics", name + ".py")
+    mod_spec = importlib.util.spec_from_file_location(
+        "metric_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.PATTERN
+
+
+@pytest.mark.parametrize("dma_depth", [1, 2], ids=["pipelined", "dma_ring"])
+def test_search_plan_names_its_kernels(compile_tpu, spec, on_tpu,
+                                       monkeypatch, dma_depth):
+    """The search plan compiled for the v5e holds instructions that the
+    benchmark's kernel patterns match, for either refine structure.  The
+    kernels' own jit wrappers are taken away, so the names come from the
+    kernels themselves and not from a Python function around them."""
+    import re
+
+    from repro.core.index import FlatIndex
+    from repro.core.search import search_plan
+    from repro.kernels import ops
+    from repro.kernels.lb_distance import lb_distance
+    from repro.kernels.refine import refine_topk
+
+    monkeypatch.setattr(ops, "resolve_interpret",
+                        lambda interpret=None: False)
+    monkeypatch.setattr(ops, "_refine_topk", refine_topk.__wrapped__)
+    monkeypatch.setattr(ops, "_lb_distance", lb_distance.__wrapped__)
+    idx = FlatIndex(series=spec((N_SERIES, L)), paa=spec((N_SERIES, W)),
+                    words=spec((N_SERIES, W), jnp.uint8),
+                    sq_norms=spec((N_SERIES,)),
+                    perm=spec((N_SERIES,), jnp.int32),
+                    valid=spec((N_SERIES,), jnp.bool_),
+                    leaf_lo=spec((NL, W)), leaf_hi=spec((NL, W)),
+                    leaf_valid=spec((NL,), jnp.bool_))
+    text = compile_tpu(
+        lambda i, q: search_plan(i, q, k=10, round_leaves=K,
+                                 backend="pallas", dma_depth=dma_depth),
+        idx, spec((128, L)))
+    kernels = [ln.strip().removeprefix("ROOT ")
+               for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for metric in ("refine_ms_per_query.batch",
+                   "lb_distance_roofline.batch"):
+        rx = re.compile(_metric_pattern(metric))
+        assert any(rx.search(n) for n in kernels), (metric, kernels)
