@@ -52,6 +52,14 @@ The four traverse-object stages map to:
                interpret mode: identical entry buffers and final
                distances (which are recomputed in direct form from the
                winners), with intra-round f32 sums equal to the last ulp.
+               Rounds run in lockstep over the batch, and a query that has
+               finished stays finished (its next lower bound only grows,
+               its k-th BSF only shrinks), so the loop runs in PHASES of
+               halving width (`phase_widths`): a phase refines only its
+               rows, and between phases the live rows are gathered to the
+               front of a batch of half the width.  A finished query's
+               refine steps then leave the kernel's grid, and no query's
+               rounds, pruning or answer change.
 
 Expeditive vs standard (Section IV) on the mesh: in the sharded search each
 device refines against its LOCAL BSF (no communication = expeditive mode)
@@ -79,8 +87,12 @@ BIG = jnp.float32(1e30)
 
 #: what the single-device plan's counter output holds, in order:
 #: refinement rounds; (query, round) pairs in which the query was live;
-#: (query, leaf) pairs whose distances the refine round computed
-COUNTERS = ("rounds", "live_query_rounds", "refined_pairs")
+#: (query, leaf) pairs whose distances the refine round computed; the
+#: rows the refine call ran with, summed over rounds
+COUNTERS = ("rounds", "live_query_rounds", "refined_pairs", "kernel_rows")
+
+#: the narrowest batch the plan refines in (`phase_widths`)
+PHASE_FLOOR = 8
 
 
 _BACKENDS = ("ref", "pallas")
@@ -279,6 +291,16 @@ def leaf_lower_bounds(idx: FlatIndex, q_paa: jnp.ndarray,
                                   series_len)
 
 
+def phase_widths(Q: int) -> Tuple[int, ...]:
+    """The batch widths the plan's refinement phases run at, in order:
+    Q, then each half (rounded down) that still holds PHASE_FLOOR rows.
+    A batch of fewer than 2 * PHASE_FLOOR rows runs in one phase."""
+    widths = [Q]
+    while widths[-1] // 2 >= PHASE_FLOOR:
+        widths.append(widths[-1] // 2)
+    return tuple(widths)
+
+
 def _refine_round(q, q_sq, series, sq_norms, ids, alive, bsf_d, bsf_e,
                   *, M: int, k: int, backend: str,
                   dma_depth: int = 1):
@@ -325,15 +347,32 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     no dispatch happens here; callers pass concrete values.
 
     Returns (dist, original_id, counts): dist/id are (Q, k) ascending by
-    distance (no k == 1 squeeze — see `run_search`); counts is the (3,)
+    distance (no k == 1 squeeze — see `run_search`); counts is the (4,)
     int32 array of the loop's own counters, in `COUNTERS` order:
-    the refinement rounds the while_loop executed (the paper's DeleteMin
+    the refinement rounds the loop executed (the paper's DeleteMin
     count; the serving layer surfaces it as rounds-per-query), the
     (query, round) pairs in which the query was still live (its next
-    unrefined lower bound beat its k-th best-so-far), and the (query,
+    unrefined lower bound beat its k-th best-so-far), the (query,
     leaf) pairs the refine round computed distances for (`alive`; dead
-    slots skip both their copy and their arithmetic).  The counts stay
-    on the device; reading them is the caller's choice.
+    slots skip both their copy and their arithmetic), and the rows the
+    refine call ran with, summed over rounds (its grid is those rows
+    times K).  The counts stay on the device; reading them is the
+    caller's choice.
+
+    Rounds run in lockstep over the batch, in phases of the widths
+    `phase_widths(Q)` gives.  A phase of B rows runs while more of them
+    are live than the next phase has rows (the last phase: while any is
+    live), all under the `n_rounds_cap` cap.  Between phases every row
+    is written back to the (Q, k) buffers, and the live rows are
+    gathered, with their queries and priority queues, to the front of
+    the next, narrower batch; dead rows fill the rest.  A query that has
+    died never comes back (its next lower bound only grows, its k-th
+    best-so-far only shrinks), and a dead row's round changes nothing,
+    so a row leaves the batch with its final buffer.  The refine kernel
+    computes one program row per query, so a query's bits do not depend
+    on the rows beside it: each query's rounds, pruning and answer are
+    those of one batch of Q rows.  At Q < 2 * PHASE_FLOOR there is one
+    phase, and the loop is that one batch.
 
     The BSF scalar of the paper generalizes to a per-query top-k buffer:
     each refinement round's real distances are folded in with
@@ -377,37 +416,73 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     n_rounds_cap = _rounds_cap(n_leaves, K, max_rounds, leaf_budget)
     order, sorted_lb = _pq_order(lb, K, n_rounds_cap, leaf_budget)
 
-    def cond(state):
-        cursor, bsf_d = state[:2]
+    cap = n_rounds_cap * K
+    # the refine kernel reads the leaf norms as (NL, 1, M) rows, laid out
+    # once here: XLA does not hoist a relayout that pads lanes out of a
+    # loop, and run once a round it rivals the round's own kernel
+    norms = (idx.sq_norms.reshape(-1, 1, M) if backend == "pallas"
+             else idx.sq_norms)
+
+    def live_rows(cursor, sorted_lb, bsf_d):
         # PQ termination: stop when the best unrefined lb >= the k-th BSF
         # (scaled by 1/(1+eps)^2 in approx mode: no remaining candidate
         # can improve the k-th answer by more than the (1+eps) factor)
         nxt = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
         bound = bsf_d[:, -1] * inv_eps if stop_eps else bsf_d[:, -1]
-        live = jnp.any(nxt[:, 0] < bound)
-        return jnp.logical_and(cursor < n_rounds_cap * K, live)
+        return nxt[:, 0] < bound
 
-    def body(state):
-        cursor, bsf_d, bsf_e, live, refined = state
-        ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
-        lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
-        # prune: leaves whose lb >= the current k-th BSF contribute
-        # nothing (approx mode shares the eps-scaled bound with cond)
-        bound = (bsf_d[:, -1:] * inv_eps if stop_eps else bsf_d[:, -1:])
-        alive = (lbs < bound)                            # (Q, K)
-        bsf_d, bsf_e = _refine_round(q, q_sq, idx.series, idx.sq_norms,
-                                     ids, alive, bsf_d, bsf_e,
-                                     M=M, k=k, backend=backend,
-                                     dma_depth=dma_depth)
-        # the PQ is sorted, so slot 0 says whether the query is live
-        live = live + jnp.sum(alive[:, 0], dtype=jnp.int32)
-        refined = refined + jnp.sum(alive, dtype=jnp.int32)
-        return cursor + K, bsf_d, bsf_e, live, refined
+    def phase(q, q_sq, order, sorted_lb, state, keep: int):
+        """Rounds over the batch's rows while more than `keep` of them
+        are live (keep == 0: while any is)."""
+        def cond(state):
+            cursor, bsf_d = state[:2]
+            live_q = live_rows(cursor, sorted_lb, bsf_d)
+            live = jnp.sum(live_q) > keep if keep else jnp.any(live_q)
+            return jnp.logical_and(cursor < cap, live)
 
+        def body(state):
+            cursor, bsf_d, bsf_e, live, refined = state
+            ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
+            lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
+            # prune: leaves whose lb >= the current k-th BSF contribute
+            # nothing (approx mode shares the eps-scaled bound with cond)
+            bound = (bsf_d[:, -1:] * inv_eps if stop_eps else bsf_d[:, -1:])
+            alive = (lbs < bound)                        # (B, K)
+            bsf_d, bsf_e = _refine_round(q, q_sq, idx.series, norms,
+                                         ids, alive, bsf_d, bsf_e,
+                                         M=M, k=k, backend=backend,
+                                         dma_depth=dma_depth)
+            # the PQ is sorted, so slot 0 says whether the query is live
+            live = live + jnp.sum(alive[:, 0], dtype=jnp.int32)
+            refined = refined + jnp.sum(alive, dtype=jnp.int32)
+            return cursor + K, bsf_d, bsf_e, live, refined
+
+        return jax.lax.while_loop(cond, body, state)
+
+    widths = phase_widths(Q)
+    batch = (q, q_sq, order, sorted_lb)
     state = (jnp.int32(0), jnp.full((Q, k), BIG),
              jnp.zeros((Q, k), jnp.int32), jnp.int32(0), jnp.int32(0))
-    cursor, bsf_d, bsf_e, live, refined = jax.lax.while_loop(cond, body,
-                                                             state)
+    kernel_rows = jnp.int32(0)
+    rows = None                 # the batch's rows of the (Q, k) buffers
+    for B, nxt_w in zip(widths, widths[1:] + (0,)):
+        start = state[0]
+        state = phase(*batch, state, keep=nxt_w)
+        cursor, bsf_d, bsf_e, live, refined = state
+        kernel_rows = kernel_rows + B * ((cursor - start) // K)
+        if rows is None:
+            out_d, out_e = bsf_d, bsf_e
+        else:
+            out_d = out_d.at[rows].set(bsf_d, unique_indices=True)
+            out_e = out_e.at[rows].set(bsf_e, unique_indices=True)
+        if nxt_w:
+            # live rows first (stable), dead ones after to fill the width
+            dead = jnp.where(live_rows(cursor, batch[3], bsf_d), 0, 1)
+            front = jnp.argsort(dead)[:nxt_w]
+            rows = front if rows is None else rows[front]
+            batch = tuple(a[front] for a in batch)
+            state = (cursor, bsf_d[front], bsf_e[front], live, refined)
+    bsf_d, bsf_e = out_d, out_e
 
     # the top-k set is exact; the matmul-form distance loses ~1e-3 absolute
     # to f32 cancellation (||q||^2+||x||^2-2qx with ||.||^2 ~ L).  Recompute
@@ -419,7 +494,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     resort = jnp.argsort(d, axis=1)
     d = jnp.sqrt(jnp.take_along_axis(d, resort, axis=1))
     ids = jnp.take_along_axis(ids, resort, axis=1)
-    return d, ids, jnp.stack([cursor // K, live, refined])
+    return d, ids, jnp.stack([cursor // K, live, refined, kernel_rows])
 
 
 search_plan = functools.partial(

@@ -224,10 +224,10 @@ def _refine_kernel_dma(ids_ref, alive_ref, q_ref, qsq_ref, bsfd_ref,
 
 
 def _leaf_norms(sq_norms, M: int, lanes: int = 0):
-    """(n_pad,) f32 norms -> (NL, 1, max(M, lanes)): one row per leaf,
-    whose block's last two dims equal the array's (see Block layout).
-    `lanes` zero-pads each row: an HBM array is tiled in 128-lane rows,
-    and a DMA may only slice whole tiles out of it."""
+    """(n_pad,) or (NL, 1, M) f32 norms -> (NL, 1, max(M, lanes)): one
+    row per leaf, whose block's last two dims equal the array's (see
+    Block layout).  `lanes` zero-pads each row: an HBM array is tiled in
+    128-lane rows, and a DMA may only slice whole tiles out of it."""
     xn = sq_norms.astype(jnp.float32).reshape(-1, 1, M)
     if lanes > M:
         xn = jnp.pad(xn, ((0, 0), (0, 0), (0, lanes - M)))
@@ -353,7 +353,10 @@ def refine_topk(q: jnp.ndarray, q_sq: jnp.ndarray, series: jnp.ndarray,
     q:        (Q, L) f32 prepared queries
     q_sq:     (Q,)   f32 ||q||^2
     series:   (n_pad, L) leaf-ordered series (any float dtype; math in f32)
-    sq_norms: (n_pad,)   f32 ||x||^2 (padded rows pushed to 1e30)
+    sq_norms: (n_pad,)   f32 ||x||^2 (padded rows pushed to 1e30), or
+              the same as (n_pad // leaf_capacity, 1, leaf_capacity)
+              rows, the layout the kernel reads (a caller that runs many
+              rounds lays it out once)
     leaf_ids: (Q, K) i32 leaves to visit this round (PQ order)
     alive:    (Q, K) bool/int — lb < round-start k-th BSF (pruning mask)
     bsf_d/e:  (Q, k) carried top-k buffer (ascending) / entry ids

@@ -5,9 +5,10 @@ that the whole process shares (an index freed after its searches leaves
 its records behind): how many queries the call held, the leaves a round
 takes per query, and the search plan's counter array
 (`repro.core.search.COUNTERS`: rounds, live query-rounds, refined
-(query, leaf) pairs), left on the device as the plan returned it.  The
-search path makes no device-to-host transfer for this; a count is
-copied to the host only when `counts` or `totals` reads it.
+(query, leaf) pairs, and the rows the refine call ran with summed over
+rounds), left on the device as the plan returned it.  The search path
+makes no device-to-host transfer for this; a count is copied to the
+host only when `counts`, `kernel_rows` or `totals` reads it.
 
 The facade also marks each call in a profiler trace: `fresh.search`
 spans the call, and `fresh.search.prepare` the host work before the
@@ -36,7 +37,7 @@ PREPARE_SPAN = "fresh.search.prepare"
 class Record:
     queries: int                 # rows of the call's query batch
     round_leaves: int            # leaves a round takes per query (K)
-    counts: Any                  # (3,) int32 device array, or None
+    counts: Any                  # (4,) int32 device array, or None
 
 
 _ring: collections.deque = collections.deque(maxlen=CAPACITY)
@@ -67,8 +68,15 @@ def counts(rec: Record) -> Optional[Tuple[int, int, int]]:
     to the host; None where the call was not counted."""
     if rec.counts is None:
         return None
-    r, live, refined = (int(v) for v in rec.counts.tolist())
+    r, live, refined = (int(v) for v in rec.counts.tolist()[:3])
     return r, live, refined
+
+
+def kernel_rows(rec: Record) -> Optional[int]:
+    """The rows the record's refine calls ran with, summed over its
+    rounds (the refine grid's steps over K), copied to the host; None
+    where the call was not counted."""
+    return None if rec.counts is None else int(rec.counts.tolist()[3])
 
 
 def totals() -> dict:

@@ -1268,11 +1268,11 @@ class QueryEngine:
         d = np.asarray(d)
         i = np.asarray(i)
         # the local plans count (rounds, live query-rounds, refined
-        # pairs) over the bucket's rows, pad rows included; the sharded
-        # plan counts its rounds alone
+        # pairs, kernel rows) over the bucket's rows, pad rows included;
+        # the sharded plan counts its rounds alone
         counts = np.atleast_1d(np.asarray(counts))
         rounds = int(counts[0])
-        counted = counts.shape[0] == 3
+        counted = counts.shape[0] > 1
         if snap.id_alias:
             # rows renamed by update() answer under their stable public
             # id; the remap uses the alias view frozen at this batch's

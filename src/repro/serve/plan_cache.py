@@ -99,7 +99,7 @@ def plan_key(k: int, knobs: Knobs) -> tuple:
 class CompiledPlan:
     """One AOT-compiled executable: fixed (bucket_Q, k, knobs, snapshot
     shape).  `run(snapshot, queries)` -> (dist (Q, k), ids (Q, k),
-    counts): the plan's (3,) counter array (`core.search.COUNTERS`).
+    counts): the plan's (4,) counter array (`core.search.COUNTERS`).
 
     `has_alive` mirrors the snapshot's tombstone state: epochs whose
     delta carries an alive mask compile (and run) the masked program —

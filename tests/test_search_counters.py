@@ -1,10 +1,12 @@
 """The search plan's own counters (rounds, live query-rounds, refined
-(query, leaf) pairs) and the facade's record of them (`repro.obs`).
+(query, leaf) pairs, kernel rows) and the facade's record of them
+(`repro.obs`).
 
 The counts are checked against the same loop re-run on the host: the
-plan's lower bounds and priority queue, then one refine round at a time,
-each round's pruning decided in numpy.  That loop's answers are also the
-plan's, bit for bit: the counters change no answer."""
+plan's lower bounds and priority queue, then one refine round at a time
+over all the batch's rows, each round's pruning decided in numpy.  That
+loop's answers are also the plan's, bit for bit: neither the counters
+nor the plan's phases of halving width change an answer."""
 
 import importlib
 
@@ -34,9 +36,12 @@ def small():
     return walks, jnp.asarray(queries), extra
 
 
-def host_loop(idx, queries, *, k, backend, stop_eps=0.0, stop_leaves=None):
+def host_loop(idx, queries, *, k, backend, stop_eps=0.0, stop_leaves=None,
+              trail=None):
     """The plan's refinement loop with its pruning and termination
-    decided on the host: ((rounds, live, refined), dist, ids)."""
+    decided on the host: ((rounds, live, refined), dist, ids).  Every
+    round refines all Q rows; `trail`, where given, gets the number of
+    live queries at the start of each round."""
     inv, budget = S._stop_knobs(stop_eps, stop_leaves, None)
     q, q_paa, q_sq = S.prepare_query_rows(queries, True, index=idx)
     lb = S.leaf_lower_bounds(idx, q_paa, L, backend)
@@ -54,6 +59,8 @@ def host_loop(idx, queries, *, k, backend, stop_eps=0.0, stop_leaves=None):
             break
         alive = sorted_lb[:, cursor:cursor + K] < bound[:, None]
         rounds += 1
+        if trail is not None:
+            trail.append(int(alive[:, 0].sum()))
         live += int(alive[:, 0].sum())
         refined += int(alive.sum())
         bsf_d, bsf_e = S._refine_round(
@@ -92,10 +99,14 @@ def test_plan_counts_match_the_host_loop(small, backend, rule, delta):
         np.testing.assert_array_equal(np.asarray(d), d_host)
         np.testing.assert_array_equal(np.asarray(i), i_host)
     assert c.dtype == jnp.int32 and c.shape == (len(S.COUNTERS),)
-    assert tuple(int(v) for v in c) == want
+    got = tuple(int(v) for v in c)
+    assert got[:3] == want
     rounds, live, refined = want
     assert 0 < live <= rounds * queries.shape[0]
     assert live <= refined <= live * K
+    # six rows: one phase, every row in every round's refine call
+    assert S.phase_widths(queries.shape[0]) == (queries.shape[0],)
+    assert got[3] == queries.shape[0] * rounds
 
     # the facade records the same counts, with a pending delta too
     if delta:
@@ -127,7 +138,8 @@ def test_search_adds_no_device_to_host_transfer(small, monkeypatch):
         d, i = ix.search(queries, k=3)
     assert copied == []
     rec = obs.records(last=1)[0]
-    assert obs.counts(rec)[0] > 0 and copied == [(3,)]   # read: copied
+    assert obs.counts(rec)[0] > 0                # read: copied
+    assert copied == [(len(S.COUNTERS),)]
     assert np.asarray(i).shape == (queries.shape[0], 3)
 
 
@@ -174,3 +186,79 @@ def test_engine_sums_match_the_facade(small, backend):
                                    for r in recs)
     visited = st["quality"]["tiers"]["exact"]["visited_leaves_per_query"]
     assert visited == pytest.approx(facade["refined_pairs"] / 8)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """64 queries whose rounds spread widely: near copies of indexed
+    series finish in a few rounds, noisier ones run on, so the number
+    of live queries falls through every phase width of a 64-row plan."""
+    walks = random_walk(2048, L, seed=51)
+    queries = np.concatenate([
+        query_workload(walks, 16, noise_sigma=sigma, seed=52 + j)
+        for j, sigma in enumerate((0.05, 0.2, 0.5, 1.0))])
+    ix = FreshIndex.build(walks, IndexConfig(leaf_capacity=M,
+                                             round_leaves=K))
+    return walks, jnp.asarray(queries), ix.index
+
+
+def phase_schedule(trail, Q):
+    """The rows each round of a phased plan refines, from the live
+    queries at the start of each round: a phase of B rows runs while
+    more than the next phase's rows are live."""
+    widths = [Q]
+    while widths[-1] // 2 >= 8:
+        widths.append(widths[-1] // 2)
+    p, rows = 0, []
+    for n in trail:
+        while p + 1 < len(widths) and n <= widths[p + 1]:
+            p += 1
+        rows.append(widths[p])
+    return widths, rows
+
+
+@pytest.mark.parametrize("k", [1, 10])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_phases_keep_every_answer_and_count(wide, backend, k):
+    """A 64-row batch runs phases of 64, 32, 16 and 8 rows; each query's
+    answer is the one it gets alone, bit for bit, and the oracle's; the
+    plan's rounds and pruning are the host loop's, which refines all 64
+    rows every round; only the refine call's rows shrink."""
+    walks, queries, idx = wide
+    Q = queries.shape[0]
+    kw = dict(k=k, round_leaves=K, backend=backend)
+    d, i, c = S.search_plan(idx, queries, **kw)
+    d, i = np.asarray(d), np.asarray(i)
+    rounds, live, refined, kernel_rows = (int(v) for v in c)
+
+    trail = []
+    want, d_host, i_host = host_loop(idx, queries, k=k, backend=backend,
+                                     trail=trail)
+    assert (rounds, live, refined) == want
+    np.testing.assert_array_equal(d, d_host)
+    np.testing.assert_array_equal(i, i_host)
+    widths, rows = phase_schedule(trail, Q)
+    assert S.phase_widths(Q) == tuple(widths) == (64, 32, 16, 8)
+    assert set(rows) == set(widths)         # every phase ran a round
+    assert kernel_rows == sum(rows)
+    assert live <= kernel_rows < Q * rounds
+
+    for j in range(Q):                      # alone: one phase
+        dj, ij, _ = S.search_plan(idx, queries[j:j + 1], **kw)
+        np.testing.assert_array_equal(np.asarray(dj)[0], d[j])
+        np.testing.assert_array_equal(np.asarray(ij)[0], i[j])
+    db, ib = S.search_bruteforce(jnp.asarray(walks), queries, k=k)
+    db, ib = np.asarray(db).reshape(Q, k), np.asarray(ib).reshape(Q, k)
+    np.testing.assert_array_equal(i, ib)
+    np.testing.assert_allclose(d, db, rtol=1e-5, atol=1e-5)
+
+    # the facade records the four counts; `obs.counts` reads three
+    obs.clear()
+    ix = FreshIndex.build(walks, IndexConfig(leaf_capacity=M,
+                                             round_leaves=K,
+                                             backend=backend))
+    ix.search(queries, k=k)
+    rec = obs.records(last=1)[0]
+    assert obs.counts(rec) == want
+    assert obs.kernel_rows(rec) == kernel_rows
+    obs.clear()

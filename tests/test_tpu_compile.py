@@ -146,7 +146,9 @@ def test_search_plan_names_its_kernels(compile_tpu, spec, on_tpu,
     """The search plan compiled for the v5e holds instructions that the
     benchmark's kernel patterns match, for either refine structure.  The
     kernels' own jit wrappers are taken away, so the names come from the
-    kernels themselves and not from a Python function around them."""
+    kernels themselves and not from a Python function around them.  The
+    128-row plan refines in phases of 128, 64, 32, 16 and 8 rows: one
+    refine call each, and the refine pattern matches every one."""
     import re
 
     from repro.core.index import FlatIndex
@@ -176,3 +178,16 @@ def test_search_plan_names_its_kernels(compile_tpu, spec, on_tpu,
                    "lb_distance_roofline.batch"):
         rx = re.compile(_metric_pattern(metric))
         assert any(rx.search(n) for n in kernels), (metric, kernels)
+    rx = re.compile(_metric_pattern("refine_ms_per_query.batch"))
+    refine = [n for n in kernels if rx.search(n)]
+    assert len(kernels) == len(refine) + 1, kernels     # and lb_distance
+    # a refine call's first output is the (rows, 1, lanes) distance buffer
+    widths = [int(re.search(r"= \(f32\[(\d+),1,", n).group(1))
+              for n in refine]
+    assert sorted(widths) == [8, 16, 32, 64, 128], widths
+    # the leaf norms are laid out as the kernel reads them once, before
+    # the rounds: no round relayouts them
+    norms = f"f32[{NL},1,{M}]"
+    in_rounds = [ln for ln in text.splitlines() if norms in ln
+                 and " reshape(" in ln and "while/body" in ln]
+    assert not in_rounds, in_rounds
