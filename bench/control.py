@@ -32,13 +32,14 @@ def readings(cell, seed: int, seconds: float, bench_dir: str) -> dict:
     run, loop, _, _ = harness.set_up(cell, seed, seconds, bench_dir)
     window = loop.measure(run, seconds)
     harness.tear_down(run, loop)
-    x = harness.series(cell, seed)
+    parts = harness.series(cell, seed)
     k = int(cell.mix["k"])
-    ref = reference.Reference(x, window.queries, k)
-    prog = reference.compare(x, window.queries, ref, window.dist,
+    ref = reference.Reference(parts, window.queries, k)
+    prog = reference.compare(parts, window.queries, ref, window.dist,
                              window.ids)
-    ctl = reference.compare(x, window.queries, ref,
-                            *reference.control_scan(x, window.queries, k))
+    ctl = reference.compare(parts, window.queries, ref,
+                            *reference.control_scan(parts, window.queries,
+                                                    k))
     return {"seed": seed, "answers": int(window.queries.shape[0]),
             "unsure": ref.unsure, "errors": window.errors,
             "program": {n: float(v.max()) for n, v in zip(NUMBERS, prog)},

@@ -6,6 +6,12 @@ The series come from `--seed`; the queries from a seed of the traffic
 mix, ordered by `--seed` (`queries`, `order`).  Each use draws from its own
 stream, so data and queries never share random numbers.  The same seed
 gives the same bits: one jitted program per shape, one call.
+
+A deployment whose series are split into S row shards (`sharded_walks`)
+draws block s, rows s * n/S onwards, from a stream of its own,
+`fold_in(seed_key(seed, stream), s)`, and makes it on the chip that
+holds it, all blocks in one program; `walk_block` makes the same block
+again on the default device.
 """
 
 from __future__ import annotations
@@ -42,6 +48,42 @@ def _walk_fn(n: int, length: int):
 def walks(seed: int, stream: int, n: int, length: int):
     """(n, length) float32 random walks on the default device."""
     return _walk_fn(n, length)(seed_key(seed, stream))
+
+
+def _block_key(seed: int, stream: int, block: int):
+    import jax
+    return jax.random.fold_in(seed_key(seed, stream), block)
+
+
+def walk_block(seed: int, stream: int, block: int, rows: int, length: int):
+    """Block `block` of a sharded set of walks, (rows, length) float32 on
+    the default device, as `sharded_walks` makes it on its own chip."""
+    return _walk_fn(rows, length)(_block_key(seed, stream, block))
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_walk_fn(rows: int, length: int, mesh):
+    import jax
+    from jax.sharding import PartitionSpec
+    spec = PartitionSpec(mesh.axis_names[0])
+    gen = _walk_fn(rows, length)
+    return jax.jit(jax.shard_map(lambda keys: gen(keys[0]), mesh=mesh,
+                                 in_specs=spec, out_specs=spec))
+
+
+def sharded_walks(seed: int, stream: int, n: int, length: int, mesh):
+    """(n, length) float32 random walks, row-sharded over the one axis of
+    `mesh`: block s, `walk_block(seed, stream, s, ...)`, made on the
+    mesh's device s by one program for the whole mesh, so no device
+    ever holds more than its own block."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+    S = mesh.devices.size
+    keys = jax.device_put(
+        jnp.stack([_block_key(seed, stream, s) for s in range(S)]),
+        NamedSharding(mesh, PartitionSpec(mesh.axis_names[0])))
+    return _sharded_walk_fn(n // S, length, mesh)(keys)
 
 
 def queries(mix: dict, n: int, length: int) -> np.ndarray:

@@ -5,7 +5,8 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name the cell gives:
 
   bench/configs/<config>.json   the deployment: sizes, IndexConfig
-                                fields, source, the limits of the check
+                                fields, "shards" (1 where absent), source,
+                                the limits of the check
   bench/traffic/<traffic>.json  the mix's parameters; its "loop" names
   bench/loops/<loop>.py         the generator and window loop that
                                 drives the entry point
@@ -17,6 +18,13 @@ seed on the device, the build, the loop's warm-up of its own shapes),
 then the measured window, then the device's peak memory, then the
 program's state is freed and the plain reference (`reference.py`)
 checks every answer the window produced.
+
+A configuration with "shards": S > 1 is a deployment whose series are
+S row blocks of n_series / S, one block on each of the cell's chips
+(`data.sharded_walks`).  The program builds its index over that placed
+array through `FreshIndex.build` and is then placed over the mesh by
+`FreshIndex.shard`.  The reference makes the blocks again, one at a
+time on one device, as the parts of `series`.
 """
 
 from __future__ import annotations
@@ -55,6 +63,11 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
 
+    @property
+    def shards(self) -> int:
+        """Row blocks of the series, one a chip."""
+        return int(self.config.get("shards", 1))
+
 
 def _json(path: str) -> dict:
     with open(path) as f:
@@ -92,7 +105,7 @@ def load_cell(name: str, root: str = ROOT,
     per_layer = [m for m in spec["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
-    return Cell(
+    cell = Cell(
         name=name, chips=int(entry["chips"]),
         config_name=entry["config"],
         config=_json(os.path.join(bench_dir, "configs",
@@ -101,6 +114,13 @@ def load_cell(name: str, root: str = ROOT,
         mix=_json(os.path.join(bench_dir, "traffic",
                                entry["traffic"] + ".json")),
         end_to_end=e2e, per_layer=per_layer)
+    if cell.shards != cell.chips:
+        raise ValueError(f"{name}: configuration {cell.config_name!r} has "
+                         f"{cell.shards} shards, the cell {cell.chips} chips")
+    if int(cell.config["n_series"]) % cell.shards:
+        raise ValueError(f"{cell.config_name}: {cell.config['n_series']} "
+                         f"series do not split into {cell.shards} shards")
+    return cell
 
 
 # --------------------------------------------------------------------- #
@@ -213,14 +233,14 @@ def log(msg: str) -> None:
 # --------------------------------------------------------------------- #
 # a run
 # --------------------------------------------------------------------- #
-def check(x, window: Window, k: int, limits: Dict[str, float]):
-    """Compare every answer of the window with the plain reference.
-    Returns (checks, wrong, unsure): each number with its limit, the
-    answers beyond a limit, and the queries the reference could not
-    certify."""
+def check(parts, window: Window, k: int, limits: Dict[str, float]):
+    """Compare every answer of the window with the plain reference over
+    the series' `parts` (`series`).  Returns (checks, wrong, unsure):
+    each number with its limit, the answers beyond a limit, and the
+    queries the reference could not certify."""
     from bench import reference
-    ref = reference.Reference(x, window.queries, k)
-    dist_err, rank_gap = reference.compare(x, window.queries, ref,
+    ref = reference.Reference(parts, window.queries, k)
+    dist_err, rank_gap = reference.compare(parts, window.queries, ref,
                                            window.dist, window.ids)
     wrong = int(((dist_err > limits["dist_err"])
                  | (rank_gap > limits["rank_gap"])).sum())
@@ -233,17 +253,39 @@ def check(x, window: Window, k: int, limits: Dict[str, float]):
     return checks, wrong, ref.unsure
 
 
-def set_up(cell: Cell, seed: int, seconds: float, bench_dir: str):
-    """The series from the seed, the index built over them, and the
-    loop's warm-up: (run, loop, shapes, seconds of each phase)."""
+def mesh_of(cell: Cell):
+    """The cell's mesh over its first `shards` devices, axis "data";
+    None for one shard."""
+    if cell.shards == 1:
+        return None
+    import jax
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:cell.shards]), ("data",))
+
+
+def make_series(cell: Cell, seed: int, mesh=None):
+    """The cell's (n_series, series_len) series from the seed: on the
+    default device, or row-sharded over `mesh` with each block made on
+    its own device."""
     from bench import data
+    n, L = int(cell.config["n_series"]), int(cell.config["series_len"])
+    if mesh is None:
+        return data.walks(seed, data.DATA, n, L)
+    return data.sharded_walks(seed, data.DATA, n, L, mesh)
+
+
+def set_up(cell: Cell, seed: int, seconds: float, bench_dir: str):
+    """The series from the seed, the index built over them (and placed
+    over the mesh where the configuration has shards), and the loop's
+    warm-up: (run, loop, shapes, seconds of each phase)."""
     from repro.api import FreshIndex, IndexConfig
     cfg = cell.config
-    n, L = int(cfg["n_series"]), int(cfg["series_len"])
+    L = int(cfg["series_len"])
     loop = load_module(bench_dir, "loops", cell.mix["loop"])
+    mesh = mesh_of(cell)
     phases = {}
     t = time.perf_counter()
-    raw = data.walks(seed, data.DATA, n, L)
+    raw = make_series(cell, seed, mesh)
     raw.block_until_ready()
     phases["data_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -251,7 +293,12 @@ def set_up(cell: Cell, seed: int, seconds: float, bench_dir: str):
     index.index.series.block_until_ready()
     phases["build_s"] = time.perf_counter() - t
     del raw
-    shapes = {"n_series": n, "series_len": L,
+    if mesh is not None:
+        t = time.perf_counter()
+        index.shard(mesh)
+        index.index.series.block_until_ready()
+        phases["shard_s"] = time.perf_counter() - t
+    shapes = {"n_series": int(cfg["n_series"]), "series_len": L,
               "n_leaves": int(index.index.n_leaves),
               "leaf_capacity": int(index.index.leaf_capacity),
               "segments": int(index.index.paa.shape[1])}
@@ -269,11 +316,21 @@ def tear_down(run: Run, loop) -> None:
     gc.collect()
 
 
-def series(cell: Cell, seed: int):
-    """The cell's series, made again from the seed for the reference."""
+def series(cell: Cell, seed: int) -> list:
+    """The cell's series made again from the seed for the reference, as
+    parts `(first_row, make)` (see `reference`), each made on the
+    default device when the reference visits it: the whole array for
+    one shard, block s of S shards for part s."""
+    import functools
+
     from bench import data
-    return data.walks(seed, data.DATA, int(cell.config["n_series"]),
-                      int(cell.config["series_len"]))
+    n, L = int(cell.config["n_series"]), int(cell.config["series_len"])
+    if cell.shards == 1:
+        return [(0, functools.partial(data.walks, seed, data.DATA, n, L))]
+    rows = n // cell.shards
+    return [(s * rows, functools.partial(data.walk_block, seed, data.DATA,
+                                         s, rows, L))
+            for s in range(cell.shards)]
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,

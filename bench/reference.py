@@ -2,10 +2,19 @@
 by a brute-force scan, and the comparison that decides `correct`.
 
 It imports nothing of the program under test and reads nothing the
-program made: it scans the series as `data.walks` makes them from the
-seed.  The scan selects candidates in matmul form at HIGHEST precision,
+program made: it scans the series as `data` makes them from the seed.
+The scan selects candidates in matmul form at HIGHEST precision,
 `margin` more than asked for, and ranks them again in float64 on the
 host, so ties and cancellation in float32 cannot change its answer.
+
+The series come as a list of parts, `(first_row, make)`: `make()`
+returns the part's (n_part, L) rows on a device, and row j of a part is
+series id `first_row + j`.  Each pass visits the parts in turn, makes
+each when it visits it and frees it before the next is made, so no
+device holds more than one part and one chunk's working set.  Each part
+is scanned in chunks as a whole array would be, and the candidates are
+merged over parts on the host: a one-part list gives the answers of one
+array.
 
 Two numbers are compared, each the widest over every answer checked:
 
@@ -96,9 +105,15 @@ def _chunk_fn(rows: int, n_cand: int, dot: str):
     return best, merge
 
 
-def scan(x, queries, n_cand: int, dot: str = "highest"):
-    """Squared distances and ids, (Q, n_cand) each on the host, of the
-    n_cand nearest rows of `x` to each query, in matmul form."""
+def _visit(parts, fn) -> list:
+    """[fn(make(), first_row) for each part]: each part resident only
+    while `fn` runs on it."""
+    return [fn(make(), first) for first, make in parts]
+
+
+def _scan_part(x, first: int, queries, n_cand: int, dot: str,
+               exact: bool):
+    """`scan` of one resident part: ids offset by `first`."""
     import jax.numpy as jnp
     n = x.shape[0]
     rows = min(CHUNK_ROWS, n)
@@ -115,7 +130,31 @@ def scan(x, queries, n_cand: int, dot: str = "highest"):
             d, i = (dc, ic) if d is None else merge(d, i, dc, ic)
         out_d.append(np.asarray(d))
         out_i.append(np.asarray(i))
-    return np.concatenate(out_d), np.concatenate(out_i)
+    d, ids = np.concatenate(out_d), np.concatenate(out_i) + first
+    if not exact:
+        return d, ids
+    return d, ids, _distances_rows(x, first, queries, ids,
+                                   np.full(ids.shape, np.nan))
+
+
+def _merge_parts(found: list, n_cand: int):
+    """The n_cand smallest first columns, per query, of the parts'
+    (d, ids, ...) tuples, with their companions; one part as it is."""
+    if len(found) == 1:
+        return found[0]
+    cols = [np.concatenate(c, axis=1) for c in zip(*found)]
+    order = np.argsort(cols[0], axis=1, kind="stable")[:, :n_cand]
+    return tuple(np.take_along_axis(c, order, axis=1) for c in cols)
+
+
+def scan(parts, queries, n_cand: int, dot: str = "highest",
+         exact: bool = False):
+    """Squared distances and ids, (Q, n_cand) each on the host, of the
+    n_cand nearest rows of the parts to each query, in matmul form; with
+    `exact`, also their float64 distances, read while each part is
+    resident."""
+    return _merge_parts(_visit(parts, lambda x, first: _scan_part(
+        x, first, queries, n_cand, dot, exact)), n_cand)
 
 
 def _znorm64(a: np.ndarray) -> np.ndarray:
@@ -124,22 +163,34 @@ def _znorm64(a: np.ndarray) -> np.ndarray:
     return a / (a.std(-1, keepdims=True) + ZNORM_EPS)
 
 
-def distances64(x, queries: np.ndarray, ids: np.ndarray) -> np.ndarray:
-    """float64 distances of the rows `ids` ((Q, c), each in range) of
-    `x` to their queries."""
+def _distances_rows(x, first: int, queries: np.ndarray, ids: np.ndarray,
+                    out: np.ndarray) -> np.ndarray:
+    """Fill `out` where `ids` lie in the resident part `x`."""
     import jax.numpy as jnp
-    rows = np.asarray(jnp.take(x, jnp.asarray(ids.reshape(-1)), axis=0))
-    rows = _znorm64(rows).reshape(ids.shape + (-1,))
-    q = _znorm64(np.asarray(queries))
-    return np.sqrt(((rows - q[:, None, :]) ** 2).sum(-1))
+    mine = (ids >= first) & (ids < first + x.shape[0])
+    if mine.any():
+        rows = _znorm64(np.asarray(jnp.take(
+            x, jnp.asarray(ids[mine] - first), axis=0)))
+        q = _znorm64(np.asarray(queries))[np.nonzero(mine)[0]]
+        out[mine] = np.sqrt(((rows - q) ** 2).sum(-1))
+    return out
+
+
+def distances64(parts, queries: np.ndarray, ids: np.ndarray) -> np.ndarray:
+    """float64 distances of the series `ids` ((Q, c)) to their queries,
+    each row taken from its own part; NaN for an id no part holds."""
+    out = np.full(ids.shape, np.nan)
+    _visit(parts, lambda x, first: _distances_rows(x, first, queries, ids,
+                                                   out))
+    return out
 
 
 class Reference:
-    """Exact k nearest (float64 distances and ids) of each query."""
+    """Exact k nearest (float64 distances and ids) of each query, over
+    the series in `parts`."""
 
-    def __init__(self, x, queries: np.ndarray, k: int):
-        d2, cand = scan(x, queries, k + MARGIN)
-        d64 = distances64(x, queries, cand)
+    def __init__(self, parts, queries: np.ndarray, k: int):
+        d2, cand, d64 = scan(parts, queries, k + MARGIN, exact=True)
         order = np.argsort(d64, axis=1, kind="stable")[:, :k]
         self.k = k
         self.dist = np.take_along_axis(d64, order, axis=1)
@@ -150,28 +201,27 @@ class Reference:
                            <= self.dist[:, -1] * (1 + 1e-4)).sum())
 
 
-def control_scan(x, queries, k: int):
+def control_scan(parts, queries, k: int):
     """The reference in the control's precision, reported as it comes:
     (Q, k) distances and ids."""
-    d2, ids = scan(x, queries, k, dot="bf16x3")
+    d2, ids = scan(parts, queries, k, dot="bf16x3")
     return np.sqrt(np.maximum(d2, 0.0)), ids
 
 
-def compare(x, queries: np.ndarray, ref: Reference, dist: np.ndarray,
+def compare(parts, queries: np.ndarray, ref: Reference, dist: np.ndarray,
             ids: np.ndarray):
     """Per query: (dist_err, rank_gap) of the answer (dist, ids) against
     `ref`, as the module docstring defines them."""
-    n = x.shape[0]
     Q, k = ref.dist.shape
     dist = np.asarray(dist, np.float64).reshape(Q, -1)
     ids = np.asarray(ids).reshape(Q, -1).astype(np.int64)
     if ids.shape[1] != k or dist.shape[1] != k:
         return np.full(Q, np.inf), np.full(Q, np.inf)
-    bad = ((ids < 0) | (ids >= n)).any(1)
+    d64 = distances64(parts, queries, ids)
+    bad = np.isnan(d64).any(1)                  # an id that no part holds
     srt = np.sort(ids, axis=1)
     bad |= (srt[:, 1:] == srt[:, :-1]).any(1)
     bad |= ~np.isfinite(dist).all(1)
-    d64 = distances64(x, queries, np.clip(ids, 0, n - 1))
     tiny = np.finfo(np.float32).tiny
     dist_err = (np.abs(dist - d64) / np.maximum(d64, tiny)).max(1)
     rank_gap = (np.abs(d64 - ref.dist)

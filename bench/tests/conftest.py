@@ -18,21 +18,26 @@ for p in (ROOT, os.path.join(ROOT, "src")):
         sys.path.insert(0, p)
 
 
-@pytest.fixture
-def tiny_root(tmp_path, monkeypatch):
-    """A copy of bench/ with the fixture's files added and the fixture's
-    BENCHMARK.json at its root.  The persistent compile cache stays off
-    in the test process."""
-    from bench import harness
-    bench = tmp_path / "bench"
+def make_tiny_root(path) -> str:
+    """At `path`: a copy of bench/ with the fixture's files added and the
+    fixture's BENCHMARK.json at its root."""
+    bench = path / "bench"
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
     for kind in ("configs", "traffic", "metrics"):
         for name in os.listdir(os.path.join(FIXTURES, kind)):
             shutil.copy(os.path.join(FIXTURES, kind, name), bench / kind)
-    shutil.copy(os.path.join(FIXTURES, "BENCHMARK.json"), tmp_path)
+    shutil.copy(os.path.join(FIXTURES, "BENCHMARK.json"), path)
+    return str(path)
+
+
+@pytest.fixture
+def tiny_root(tmp_path, monkeypatch):
+    """`make_tiny_root` in a fresh directory.  The persistent compile
+    cache stays off in the test process."""
+    from bench import harness
     monkeypatch.setattr(harness, "use_compile_cache", lambda root: "off")
-    return str(tmp_path)
+    return make_tiny_root(tmp_path)
 
 
 @pytest.fixture

@@ -89,11 +89,11 @@ def test_control_in_the_programs_place_is_not_correct(tiny_root, run_tiny,
     from bench import harness, reference
     from repro.api import FreshIndex
     seed = 2**31 + 91
-    x = harness.series(harness.load_cell("tiny-rw64.batch", root=tiny_root),
-                       seed)
+    parts = harness.series(
+        harness.load_cell("tiny-rw64.batch", root=tiny_root), seed)
 
     def control(self, queries, k=1, **kw):
-        d, i = reference.control_scan(x, np.asarray(queries), k)
+        d, i = reference.control_scan(parts, np.asarray(queries), k)
         return jnp.asarray(d), jnp.asarray(i)
 
     monkeypatch.setattr(FreshIndex, "search", control)

@@ -82,3 +82,58 @@ def test_run_exits_nonzero_with_only_the_benchmark_files(tmp_path):
     p = _run_py(str(tmp_path), {"PYTHONPATH": ""})
     assert p.returncode != 0
     assert p.stdout.strip() == ""
+
+
+#: sha256 of `data.walks(2**31 + 77, data.DATA, 4096, 64)`, tiny-rw64's
+#: series at that seed, computed on the CPU before the harness took
+#: sharded deployments
+PINNED_SERIES = ("f0d0ac8ab8e45f7ce8fb738eacf5d7b4"
+                 "7bdfe997fb0daae58ffc6485256350a1")
+
+
+def test_one_shard_series_keep_their_bits(tiny_root):
+    import hashlib
+
+    import numpy as np
+
+    def digest(x):
+        return hashlib.sha256(np.asarray(x).tobytes()).hexdigest()
+    cell = harness.load_cell("tiny-rw64.batch", root=tiny_root)
+    assert cell.shards == 1 and harness.mesh_of(cell) is None
+    assert digest(harness.make_series(cell, 2**31 + 77)) == PINNED_SERIES
+    [(first, make)] = harness.series(cell, 2**31 + 77)
+    assert first == 0 and digest(make()) == PINNED_SERIES
+
+
+@pytest.mark.parametrize("chips,shards", [(1, 4), (4, 1), (2, 4), (3, 3)])
+def test_shards_must_match_the_chips_and_split_the_series(tiny_root, chips,
+                                                          shards):
+    """A configuration whose shards are not its cell's chips, or do not
+    divide its 4096 series, is refused before any work."""
+    spec_path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(spec_path) as f:
+        spec = json.load(f)
+    cell = next(w for w in spec["workloads"]
+                if w["name"] == "tiny-rw64-4shard.batch")
+    cell["chips"] = chips
+    with open(spec_path, "w") as f:
+        json.dump(spec, f)
+    cfg_path = os.path.join(tiny_root, "bench", "configs",
+                            "tiny-rw64-4shard.json")
+    with open(cfg_path) as f:
+        cfg = json.load(f)
+    cfg["shards"] = shards
+    with open(cfg_path, "w") as f:
+        json.dump(cfg, f)
+    with pytest.raises(ValueError, match="shards"):
+        harness.load_cell("tiny-rw64-4shard.batch", root=tiny_root)
+
+
+def test_a_sharded_cell_is_found_by_name(tiny_root):
+    cell = harness.load_cell("tiny-rw64-4shard.batch", root=tiny_root)
+    assert cell.chips == cell.shards == 4
+    assert [m["name"] for m in cell.end_to_end] == ["queries_per_s",
+                                                    "setup_s"]
+    parts = harness.series(cell, 5)
+    assert [first for first, _ in parts] == [0, 1024, 2048, 3072]
+    assert all(callable(make) for _, make in parts)

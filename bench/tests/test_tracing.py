@@ -130,3 +130,41 @@ def test_a_recorded_stretch_reduces_as_a_trace(tiny_root, tmp_path):
                for events in [back.host, *back.ops.values()]
                for _, s, d in events)
     assert 0 <= tracing.busy_s(back) <= back.window_s
+
+
+def test_four_chip_trace():
+    """A synthetic trace of four chips: busy time and the idle share are
+    a mean chip's, kernel time is summed over the chips, and the idle
+    gaps are read from the lowest chip id."""
+    us = 1000
+    window = (0, 100 * us)
+    ops = {
+        # chip 2 lists first: the lowest id, not the first listed, names
+        # the gaps
+        2: [("%refine_topk.1", 0, 100 * us)],
+        0: [("%refine_topk.1", 10 * us, 30 * us),
+            ("%fusion.3", 20 * us, 30 * us),       # overlaps the refine
+            ("%lb_distance.2", 70 * us, 10 * us)],
+        1: [("%refine_topk.1", 0, 50 * us)],
+        3: [("%refine_topk.1", 90 * us, 20 * us)],  # runs past the window
+    }
+    host = [("bench.search", 0, 100 * us), ("fresh.search", 40 * us, 30 * us)]
+    tr = tracing.Trace(ops, host, window)
+    busy = {0: 50, 1: 50, 2: 100, 3: 10}                 # us, each chip
+    assert tracing.busy_s(tr) == pytest.approx(
+        sum(busy.values()) / 4 * 1e-6)
+    assert tracing.idle_share(tr) == pytest.approx(1 - 52.5 / 100)
+    refine, n = tracing.op_seconds(tr, r"^%refine_topk\.")
+    assert n == 4 and refine == pytest.approx((30 + 50 + 100 + 10) * 1e-6)
+    # chip 0's holes: 0-10 us, 50-70 us (inside fresh.search, which covers
+    # at least half of it and is the shortest such), 80-100 us
+    assert tracing.idle_gaps(tr) == [("bench.search", 10 * us),
+                                     ("fresh.search", 20 * us),
+                                     ("bench.search", 20 * us)]
+    b = tracing.breakdown(tr)
+    # self time, over chips: on chip 0 the fusion takes 20-40 us of the
+    # refine's 10-40 us
+    assert dict(b["device_ops"])["%refine_topk.1"] == pytest.approx(
+        (10 + 50 + 100 + 10) * 1e-6)
+    assert b["idle_gaps"] == [["bench.search", 30e-6],
+                              ["fresh.search", 20e-6]]

@@ -9,12 +9,18 @@ reduced again without a chip.
 
   busy_s       union of the intervals in which an operation ran on a
                chip, inside the window, averaged over the chips
-  idle_gaps    the holes in that union, each named by what the host
-               was doing then
-  op_seconds   summed device time of the operations whose name matches
-               a pattern (how a kernel's time is read)
-  breakdown    the ten operations that took most time, and the idle
-               time summed by what the host was doing
+  idle_gaps    the holes in one chip's union (the lowest chip id), each
+               named by what the host was doing then
+  op_seconds   device time of the operations whose name matches a
+               pattern, summed over the chips (how a kernel's time is
+               read)
+  breakdown    the ten operations that took most time, summed over the
+               chips, and the lowest chip's idle time summed by what the
+               host was doing
+
+A run on several chips holds one list of operations a chip: busy time
+and the idle share are a mean chip's, kernel time is all the chips'
+together.
 """
 
 from __future__ import annotations
@@ -134,7 +140,8 @@ def busy_intervals(events: List[Event], lo: int, hi: int
 
 def busy_s(trace: Trace) -> float:
     """Seconds of the window in which some operation ran, averaged over
-    the chips in the trace."""
+    the chips in the trace: each chip's busy union, summed, over the
+    number of chips (a run on four chips reads a mean chip)."""
     if not trace.ops:
         return 0.0
     lo, hi = trace.window
