@@ -353,6 +353,14 @@ def stub_topk_alive(q: np.ndarray, data: np.ndarray,
     return dd, ii
 
 
+def stub_counts(queries: np.ndarray) -> np.ndarray:
+    """The stub plans' counter array, in `core.search.COUNTERS` order: a
+    scan counted as one round in which every query is live and refines
+    one leaf."""
+    q = queries.shape[0]
+    return np.array([1, q, q, q, 1], np.int32)
+
+
 class _StubPlan:
     __slots__ = ("k",)
 
@@ -378,7 +386,7 @@ class _StubPlan:
         d, i = stub_topk_alive(q, np.concatenate(data, axis=0),
                                np.concatenate(ids),
                                None if a.all() else a, self.k)
-        return d, i, 1
+        return d, i, stub_counts(q)
 
 
 class StubPlans:
@@ -455,7 +463,7 @@ class _QualityStubPlan(_StubPlan):
         d, i = stub_topk_alive(q, np.concatenate(data, axis=0),
                                np.concatenate(ids),
                                None if a.all() else a, self.k)
-        return d, i, 1
+        return d, i, stub_counts(q)
 
 
 class QualityStubPlans(StubPlans):

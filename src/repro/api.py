@@ -100,9 +100,9 @@ from repro.analysis.hooks import observe
 from repro.checkpoint.store import load_arrays, save_checkpoint
 from repro.core import isax
 from repro.core.builder import IndexBuilder, merge_sorted_delta
-from repro.core.index import (FlatIndex, build_index, index_stats,
-                              pad_leaves)
-from repro.core.search import (build_sharded_search, merge_delta_topk,
+from repro.core.index import (FlatIndex, build_index, build_index_sharded,
+                              index_stats, pad_leaves, rows_placement)
+from repro.core.search import (build_sharded_plan, merge_delta_topk,
                                search_plan, shard_index, squeeze_k)
 from repro.maintenance.tombstones import (core_dead_mask, delta_alive_mask,
                                           mask_core)
@@ -252,7 +252,11 @@ class FreshIndex:
         Args:
             data: (n, L) series matrix (host or device array; a device
                 array is built in place, never copied through the host);
-                n == 0 is the legal bootstrap.
+                n == 0 is the legal bootstrap.  A jax.Array whose rows
+                are split evenly over one axis of a mesh (as
+                `jax.device_put(x, NamedSharding(mesh, P("data")))`
+                places them) is built on the mesh, each device over its
+                own rows, and the index comes back placed there.
             config: IndexConfig (None = defaults).
             **overrides: IndexConfig fields, so the two spellings
                 `build(x, IndexConfig(leaf_capacity=32))` and
@@ -264,7 +268,12 @@ class FreshIndex:
                 `config.validate_series_len`.
 
         Dispatches to the fused single-program `build_index` jit — the
-        fastest one-shot path.  The `IndexBuilder` phase pipeline
+        fastest one-shot path; row-sharded data to
+        `build_index_sharded`, one program for the mesh in which each
+        device runs the same build over its own block: the ids are the
+        rows' global positions, each device's leaves cover its rows
+        alone, and `mesh` is set (`shard` on that mesh moves nothing).
+        The `IndexBuilder` phase pipeline
         (streaming feed, lock-free multi-worker builds via
         `FreshIndex.builder`, incremental compaction) produces
         bit-identical arrays, proven by tests/test_builder.py::
@@ -275,6 +284,11 @@ class FreshIndex:
         Concurrency: pure construction — no shared state until the
         returned index is handed to readers.
         """
+        with jax.profiler.TraceAnnotation(obs.BUILD_SPAN):
+            return cls._build(data, config, overrides)
+
+    @classmethod
+    def _build(cls, data, config, overrides) -> "FreshIndex":
         cfg = config or IndexConfig()
         if overrides:
             cfg = dataclasses.replace(cfg, **overrides)
@@ -285,14 +299,21 @@ class FreshIndex:
         if data.shape[0] == 0:
             return cls.builder(cfg).feed(np.asarray(data)).finalize()
         cfg.validate_series_len(data.shape[1])
-        idx = build_index(jnp.asarray(data), segments=cfg.segments,
-                          bits=cfg.bits, leaf_capacity=cfg.leaf_capacity,
-                          znorm=cfg.znorm, bound=cfg.bound,
-                          backend=cfg.backend)
+        placed = rows_placement(data)
+        kw = dict(segments=cfg.segments, bits=cfg.bits,
+                  leaf_capacity=cfg.leaf_capacity, znorm=cfg.znorm,
+                  bound=cfg.bound, backend=cfg.backend)
+        if placed is None:
+            idx = build_index(jnp.asarray(data), **kw)
+        else:
+            idx = build_index_sharded(data, *placed, **kw)
         if cfg.dtype != "float32":
             dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float16
             idx = idx._replace(series=idx.series.astype(dt))
-        return cls(idx, cfg)
+        out = cls(idx, cfg)
+        if placed is not None:
+            out._mesh, out._mesh_axis = placed
+        return out
 
     @classmethod
     def builder(cls, config: Optional[IndexConfig] = None,
@@ -379,8 +400,7 @@ class FreshIndex:
         queries, rounds, live query-rounds (a query is live in a round
         when its next unrefined lower bound beats its k-th best so far)
         and refined (query, leaf) pairs; `repro.obs.records()` holds
-        them call by call.  A sharded index's calls count searches and
-        queries only.
+        them call by call, a sharded index's counted over its shards.
         """
         st = index_stats(self._idx)
         st["n_pending"] = self.n_pending
@@ -459,22 +479,23 @@ class FreshIndex:
                        bk, dd, rule, mesh_sig(self._mesh))
                 fn = self._sharded_fns.get(key)
                 if fn is None:
-                    fn = build_sharded_search(
+                    fn = jax.jit(build_sharded_plan(
                         self._mesh, axis=self._mesh_axis, k=k,
                         round_leaves=rl, sync_every=sync_every,
                         max_rounds=max_rounds, znorm=self.config.znorm,
                         pq_budget=pqb, backend=bk,
-                        dma_depth=dd, config=self.config, **rule.lower())
+                        dma_depth=dd, config=self.config, **rule.lower()))
                     self._sharded_fns[key] = fn
-                d, i = fn(core, q)
-                counts = None             # the sharded plan counts nothing
+                d, i, counts = fn(core, q)
+                shards = self._mesh.shape[self._mesh_axis]
             else:
                 # the knobs are resolved: dispatch the jitted plan
                 d, i, counts = search_plan(
                     core, q, k=k, round_leaves=rl, znorm=self.config.znorm,
                     max_rounds=max_rounds, pq_budget=pqb, backend=bk,
                     dma_depth=dd, **rule.lower())
-                d, i = squeeze_k(d, i, k)
+                shards = 1
+            d, i = squeeze_k(d, i, k)
             if delta is not None:
                 # fold the exact delta scan into the core answer.  The
                 # core search program stays cached across add() calls;
@@ -490,7 +511,7 @@ class FreshIndex:
                 d, i = squeeze_k(md, mi, k)
             if self._alias:
                 i = jnp.asarray(self._remap_ids(np.asarray(i)))
-            obs.record(q.shape[0], rl, counts)
+            obs.record(q.shape[0], rl, counts, shards)
             return d, i
 
     def _search_args(self, queries, k, mode, recall_target, stop_eps,
@@ -1071,22 +1092,27 @@ class FreshIndex:
         """Block-shard the leaves (and their entries) over the `axis`
         axis of `mesh`, padding to a whole number of leaves per device,
         and route subsequent search() calls through the sharded
-        expeditive/standard path.  Returns self.
+        expeditive/standard path.  Returns self; an index already placed
+        over `axis` of `mesh` (a build over row-sharded data) as it is.
 
         Concurrency: a writer (replaces the placed arrays and drops the
         compiled-search cache); serialize like add/compact.  A serving
         engine re-places through recover(), never this method directly.
         """
-        n_dev = mesh.shape[axis]
-        self._idx = shard_index(pad_leaves(self._idx, n_dev), mesh, axis=axis)
-        self._mesh = mesh
-        self._mesh_axis = axis
-        self._sharded_fns = {}
-        # the masked search view wraps the (now stale) placement
-        self._masked = None
-        self._masked_key = None
-        self._lifecycle_ver += 1
-        return self
+        with jax.profiler.TraceAnnotation(obs.SHARD_SPAN):
+            if self._mesh == mesh and self._mesh_axis == axis:
+                return self
+            n_dev = mesh.shape[axis]
+            self._idx = shard_index(pad_leaves(self._idx, n_dev), mesh,
+                                    axis=axis)
+            self._mesh = mesh
+            self._mesh_axis = axis
+            self._sharded_fns = {}
+            # the masked search view wraps the (now stale) placement
+            self._masked = None
+            self._masked_key = None
+            self._lifecycle_ver += 1
+            return self
 
     # ------------------------------------------------------------------ #
     # persistence

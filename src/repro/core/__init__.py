@@ -37,7 +37,8 @@ from . import isax  # noqa: F401
 from .builder import IndexBuilder, merge_sorted_delta  # noqa: F401
 from .dtw import lb_keogh, dtw_band, search_dtw  # noqa: F401
 from .index import (FlatIndex, build_index, build_index_host,  # noqa: F401
-                    index_stats, leaf_stats_blocks, pad_leaves)
+                    build_index_sharded, index_stats, leaf_stats_blocks,
+                    pad_leaves)
 from .refresh import (CounterObject, Injectors, RefreshExecutor,  # noqa: F401
                       RefreshRun, WorkerCrash)
 from .search import (build_sharded_plan, build_sharded_search,  # noqa: F401

@@ -21,19 +21,27 @@ Three lower bounds, all sound (tests prove the pruning property for each):
     'paabox' — per-leaf min/max raw PAA box.                   [tightest]
 
 Locality (Definition IV.1) on the mesh: leaves are block-sharded over the
-'data' axis, so every device owns a contiguous key range — disjoint data,
-zero intra-stage communication, balanced by construction (equal block
-counts), i.e. the three locality-aware principles survive the port.
+'data' axis, so every device owns its own leaves — disjoint data, zero
+intra-stage communication, balanced by construction (equal block counts),
+i.e. the three locality-aware principles survive the port.  What a
+device's leaves cover depends on how the index was built: `shard_index`
+over a `build_index` result gives each device a contiguous range of the
+global key order, while `build_index_sharded` (a build over series
+already row-sharded over the mesh) sorts each device's own rows alone,
+so a device's leaves cover its rows only, in their own key order, and
+the key ranges of different devices overlap.  The search is exact
+either way: every device prunes with its own leaves' bounds.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import isax
 
@@ -124,7 +132,9 @@ def build_index(raw: jnp.ndarray,
     raw: (n, L) float series.  n is padded up to a leaf multiple.
     The global sort is the only step with cross-shard dataflow (an all-to-all
     under pjit) — everything else is embarrassingly local, mirroring the
-    paper's "threads work on disjoint buffers/subtrees" design.
+    paper's "threads work on disjoint buffers/subtrees" design.  Series
+    already row-sharded over a mesh build shard by shard instead
+    (`build_index_sharded`), with no cross-shard dataflow at all.
 
     backend 'pallas' runs the summarization stage through the fused Pallas
     kernel (Mosaic on TPU, interpret elsewhere); 'ref' is pure jnp.
@@ -136,6 +146,18 @@ def build_index(raw: jnp.ndarray,
     phase-equivalence tests in tests/test_builder.py proving the two
     paths produce bit-identical indexes.
     """
+    return _build_body(raw, segments=segments, bits=bits,
+                       leaf_capacity=leaf_capacity, znorm=znorm,
+                       bound=bound, backend=backend)
+
+
+def _build_body(raw: jnp.ndarray, *, segments: int, bits: int,
+                leaf_capacity: int, znorm: bool, bound: str, backend: str,
+                first_row=None) -> FlatIndex:
+    """The build of one block of rows: what `build_index` runs over the
+    whole array and `build_index_sharded` over each device's block.
+    `first_row` (None: 0) is the global id of the block's first row; the
+    ids in `perm` are offset by it."""
     n, L = raw.shape
 
     def prepare(r):
@@ -151,10 +173,11 @@ def build_index(raw: jnp.ndarray,
         p, w = isax.summarize(prepare(raw), segments, bits)
 
     # ---- sort by interleaved key (leaf order of the round-robin tree) ----
-    perm = isax.lexsort_lanes(isax.interleaved_key(w, bits))
+    order = isax.lexsort_lanes(isax.interleaved_key(w, bits))
     # normalize AFTER the gather (row-wise, so the same values): the
     # gather and the normalization fuse into one pass writing the output
-    x, p, w = prepare(raw[perm]), p[perm], w[perm]
+    x, p, w = prepare(raw[order]), p[order], w[order]
+    perm = order if first_row is None else order + first_row
 
     # ---- pad to a whole number of leaves ---------------------------------
     n_pad = -(-n // leaf_capacity) * leaf_capacity
@@ -183,6 +206,66 @@ def build_index(raw: jnp.ndarray,
     return FlatIndex(series=x, paa=p, words=w, sq_norms=sq_norms,
                      perm=perm, valid=valid,
                      leaf_lo=lo, leaf_hi=hi, leaf_valid=leaf_valid)
+
+
+def rows_placement(data) -> Optional[Tuple[Mesh, str]]:
+    """(mesh, axis) where `data` is a 2-D jax.Array whose rows are split
+    evenly over one axis, of more than one device, of a mesh and whose
+    columns are not split; None for any other array (a host array, one
+    device, a replicated or column-split placement)."""
+    sh = getattr(data, "sharding", None)
+    if not isinstance(sh, NamedSharding) or getattr(data, "ndim", 0) != 2:
+        return None
+    spec = tuple(sh.spec) + (None, None)
+    axis = spec[0]
+    if isinstance(axis, tuple) and len(axis) == 1:
+        axis = axis[0]
+    if not isinstance(axis, str) or spec[1] is not None:
+        return None
+    n_dev = sh.mesh.shape[axis]
+    if n_dev < 2 or data.shape[0] % n_dev:
+        return None
+    return sh.mesh, axis
+
+
+def build_index_sharded(raw: jnp.ndarray, mesh: Mesh, axis: str = "data",
+                        *, segments: int = isax.SEGMENTS,
+                        bits: int = isax.SAX_BITS, leaf_capacity: int = 64,
+                        znorm: bool = True, bound: str = "prefix",
+                        backend: str = "ref") -> FlatIndex:
+    """Build over series row-sharded over `axis` of `mesh`, as one
+    program for the mesh in which each device builds its own block
+    (`_build_body`, the body `build_index` runs): summarize (the Pallas
+    kernel runs per device, never partitioned), sort by key, pad to whole
+    leaves, leaf summaries and norms.  Block s of S holds rows s * n/S
+    onwards, and its ids are offset by that.
+
+    The result is leaf-sharded as `shard_index` places an index: every
+    array split over `axis` by rows, each device holding the leaves of its
+    own rows.  A device's leaves therefore cover its rows alone, not a
+    range of the global key order; each block pads to whole leaves on its
+    own, so padding (perm -1) can sit at the end of every block."""
+    return _sharded_build_fn(mesh, axis, segments, bits, leaf_capacity,
+                             znorm, bound, backend)(raw)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_build_fn(mesh: Mesh, axis: str, segments: int, bits: int,
+                      leaf_capacity: int, znorm: bool, bound: str,
+                      backend: str):
+    rows, entries = P(axis, None), P(axis)
+
+    def local(block):
+        first = jax.lax.axis_index(axis) * block.shape[0]
+        return _build_body(block, segments=segments, bits=bits,
+                           leaf_capacity=leaf_capacity, znorm=znorm,
+                           bound=bound, backend=backend, first_row=first)
+
+    out_specs = FlatIndex(series=rows, paa=rows, words=rows,
+                          sq_norms=entries, perm=entries, valid=entries,
+                          leaf_lo=rows, leaf_hi=rows, leaf_valid=entries)
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=rows,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def pad_leaves(idx: FlatIndex, multiple: int) -> FlatIndex:

@@ -16,11 +16,11 @@ one code path —
                      default) + the historical k == 1 squeeze; the
                      facade folds a pending delta in via merge_delta_topk
   build_sharded_plan the pure sharded plan factory ((Q, k) outputs plus
-                     the replicated round count) — what the sharded
-                     serving path AOT-compiles per (bucket, k, mesh)
+                     the same `COUNTERS`, counted over the shards) — what
+                     the sharded facade and serving path run
   build_sharded_search
-                     jit + squeeze over build_sharded_plan — what the
-                     sharded FreshIndex.search dispatches through
+                     jit + squeeze over build_sharded_plan, behind the
+                     deprecated make_sharded_search
   search / make_sharded_search
                      DEPRECATED free-function shims (DeprecationWarning
                      pointing at the repro.api migration table)
@@ -85,11 +85,16 @@ from .index import FlatIndex
 
 BIG = jnp.float32(1e30)
 
-#: what the single-device plan's counter output holds, in order:
-#: refinement rounds; (query, round) pairs in which the query was live;
-#: (query, leaf) pairs whose distances the refine round computed; the
-#: rows the refine call ran with, summed over rounds
-COUNTERS = ("rounds", "live_query_rounds", "refined_pairs", "kernel_rows")
+#: what the plans' counter output holds, in order: refinement rounds;
+#: (query, round) pairs in which the query was live; (query, leaf) pairs
+#: whose distances the refine round computed; the rows the refine call
+#: ran with, summed over rounds; the rounds in which a shard refined at
+#: least one pair.  The sharded plan counts the same over S shards:
+#: rounds of its collective loop, a query live where it is live on any
+#: shard, and the last three summed over the shards (one device: the
+#: last equals the rounds)
+COUNTERS = ("rounds", "live_query_rounds", "refined_pairs", "kernel_rows",
+            "shard_live_rounds")
 
 #: the narrowest batch the plan refines in (`phase_widths`)
 PHASE_FLOOR = 8
@@ -347,7 +352,7 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     no dispatch happens here; callers pass concrete values.
 
     Returns (dist, original_id, counts): dist/id are (Q, k) ascending by
-    distance (no k == 1 squeeze — see `run_search`); counts is the (4,)
+    distance (no k == 1 squeeze — see `run_search`); counts is the (5,)
     int32 array of the loop's own counters, in `COUNTERS` order:
     the refinement rounds the loop executed (the paper's DeleteMin
     count; the serving layer surfaces it as rounds-per-query), the
@@ -356,8 +361,10 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     leaf) pairs the refine round computed distances for (`alive`; dead
     slots skip both their copy and their arithmetic), and the rows the
     refine call ran with, summed over rounds (its grid is those rows
-    times K).  The counts stay on the device; reading them is the
-    caller's choice.
+    times K), and the rounds in which the one shard refined anything
+    (here every round: a round runs only while some query is live).
+    The counts stay on the device; reading them is the caller's
+    choice.
 
     Rounds run in lockstep over the batch, in phases of the widths
     `phase_widths(Q)` gives.  A phase of B rows runs while more of them
@@ -494,7 +501,8 @@ def search_plan_impl(idx: FlatIndex, queries: jnp.ndarray, *,
     resort = jnp.argsort(d, axis=1)
     d = jnp.sqrt(jnp.take_along_axis(d, resort, axis=1))
     ids = jnp.take_along_axis(ids, resort, axis=1)
-    return d, ids, jnp.stack([cursor // K, live, refined, kernel_rows])
+    rounds = cursor // K
+    return d, ids, jnp.stack([rounds, live, refined, kernel_rows, rounds])
 
 
 search_plan = functools.partial(
@@ -752,7 +760,7 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
                        dma_depth: Optional[int] = None,
                        tune=None, config=None):
     """The PURE sharded search plan factory: `(idx, queries) -> (dist,
-    ids, rounds)` with (Q, k) outputs and no squeeze — the sharded
+    ids, counts)` with (Q, k) outputs and no squeeze — the sharded
     analogue of `search_plan_impl`.
 
     Each device: local lower bounds + local partial-selection PQ + local
@@ -763,15 +771,22 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
     (its k candidates are all <= it and all belong to the union), so the
     pmin over devices is too.  The final (dist, id) top-k is resolved by
     all-gathering the n_dev local buffers and re-top-k'ing the union.
-    `rounds` is the (replicated) refinement-round count of the collective
-    while_loop — every device executes the same number of iterations
-    because the loop condition is itself an all-reduce.
+    The loop is collective: after each round an all-reduce-max tells
+    every device which queries are still live on some device, and the
+    loop runs while any is, so every device executes the same rounds.
+
+    `counts` is the (5,) int32 `COUNTERS` array, replicated: the rounds
+    of the collective loop; the (query, round) pairs in which the query
+    was live on any device; and, summed over the devices, the (query,
+    leaf) pairs refined, the rows the refine calls ran with (every round
+    refines all Q rows on every device), and the rounds in which the
+    device refined at least one pair — a device whose queries are all
+    pruned locally still runs the round, at its lock-step cost.
 
     The returned function is traceable but NOT jitted: `FreshIndex.search`
-    dispatches it through the jit in `build_sharded_search`, and the
-    serving layer (`serve.PlanCache`) AOT-compiles the very same function
-    per (batch-bucket, k, mesh layout) with `.lower().compile()`, so the
-    two paths execute identical programs.
+    and the serving layer (`serve.PlanCache`, AOT per (batch-bucket, k,
+    mesh layout)) jit the very same function, so the two paths execute
+    identical programs.
 
     backend / round_leaves / pq_budget / dma_depth resolve from
     `config` (IndexConfig) when unset, then from `tune` (a fresh autotune
@@ -816,6 +831,9 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
 
         cap = _rounds_cap(n_leaves_local, K, max_rounds, leaf_budget)
         order, sorted_lb = _pq_order(lb, K, cap, leaf_budget)
+        # the leaf norms as the refine kernel reads them, laid out once
+        # before the loop (see search_plan_impl)
+        norms = sq_norms.reshape(-1, 1, M) if bk == "pallas" else sq_norms
 
         # Two accumulators per query:
         #   bsf_d/bsf_e — the LOCAL top-k buffer (never overwritten by
@@ -824,41 +842,46 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
         #          (standard-mode sync).  Pruning/termination use
         #          min(pb, local k-th), eps-scaled in approx mode like
         #          the local plan's cond.
-        def refine(cursor, bsf_d, bsf_e, pb):
-            ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
-            lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
+        def bound_of(bsf_d, pb):
             bound = jnp.minimum(pb, bsf_d[:, -1])
-            if stop_eps:
-                bound = bound * inv_eps
-            alive = lbs < bound[:, None]
-            return _refine_round(q, q_sq, series, sq_norms, ids, alive,
-                                 bsf_d, bsf_e, M=M, k=k, backend=bk,
-                                 dma_depth=dd)
+            return bound * inv_eps if stop_eps else bound
+
+        def live_anywhere(cursor, bsf_d, pb):
+            # (Q,) 1 where the query's next unrefined lower bound beats
+            # its bound on some device (the PQ is sorted: slot 0 decides)
+            nxt = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
+            live = (nxt[:, 0] < bound_of(bsf_d, pb)).astype(jnp.int32)
+            return jax.lax.pmax(live, axis)
 
         def cond(state):
-            cursor, bsf_d, _, pb, rounds = state
-            nxt = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
-            bound = jnp.minimum(pb, bsf_d[:, -1])
-            if stop_eps:
-                bound = bound * inv_eps
-            live_local = jnp.any(nxt[:, 0] < bound)
-            live = jax.lax.pmax(live_local.astype(jnp.int32), axis)
-            return jnp.logical_and(cursor < cap * K, live > 0)
+            cursor, live_q = state[0], state[5]
+            return jnp.logical_and(cursor < cap * K, jnp.any(live_q > 0))
 
         def body(state):
-            cursor, bsf_d, bsf_e, pb, rounds = state
-            bsf_d, bsf_e = refine(cursor, bsf_d, bsf_e, pb)
+            cursor, bsf_d, bsf_e, pb, rounds, live_q, counts = state
+            ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
+            lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
+            alive = lbs < bound_of(bsf_d, pb)[:, None]
+            bsf_d, bsf_e = _refine_round(q, q_sq, series, norms, ids, alive,
+                                         bsf_d, bsf_e, M=M, k=k, backend=bk,
+                                         dma_depth=dd)
             # standard mode: publish the global k-th bound every sync_every
             do_sync = (rounds % sync_every) == (sync_every - 1)
             gbsf = jax.lax.pmin(bsf_d[:, -1], axis)
             pb = jnp.where(do_sync, jnp.minimum(pb, gbsf), pb)
-            return cursor + K, bsf_d, bsf_e, pb, rounds + 1
+            # live query-rounds, refined pairs, rounds this device worked
+            n_alive = jnp.sum(alive, dtype=jnp.int32)
+            counts = counts + jnp.stack([jnp.sum(live_q), n_alive,
+                                         (n_alive > 0).astype(jnp.int32)])
+            return (cursor + K, bsf_d, bsf_e, pb, rounds + 1,
+                    live_anywhere(cursor + K, bsf_d, pb), counts)
 
-        Qn = q.shape[0]
-        state = (jnp.int32(0), jnp.full((Qn, k), BIG),
-                 jnp.zeros((Qn, k), jnp.int32), jnp.full((Qn,), BIG),
-                 jnp.int32(0))
-        _, bsf_d, bsf_e, _, rounds = jax.lax.while_loop(cond, body, state)
+        bsf_d, pb = jnp.full((Q, k), BIG), jnp.full((Q,), BIG)
+        state = (jnp.int32(0), bsf_d, jnp.zeros((Q, k), jnp.int32), pb,
+                 jnp.int32(0), live_anywhere(0, bsf_d, pb),
+                 jnp.zeros((3,), jnp.int32))
+        _, bsf_d, bsf_e, _, rounds, _, (live, refined, worked) = \
+            jax.lax.while_loop(cond, body, state)
 
         # recompute the local winners' distances in DIRECT form (matmul
         # form loses ~1e-3 absolute to f32 cancellation — see search())
@@ -874,9 +897,11 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
         neg, pos = jax.lax.top_k(-all_d, k)              # ascending
         dist = -neg
         bid = jnp.take_along_axis(all_i, pos, axis=1)
-        # rounds is replicated: the while_loop condition is collective
-        # (pmax over devices), so every device ran the same iterations
-        return jnp.sqrt(dist), bid, rounds
+        # rounds and live are replicated (the loop and its liveness are
+        # collective); the rest are this device's, summed over devices
+        summed = jax.lax.psum(jnp.stack([refined, Q * rounds, worked]), axis)
+        counts = jnp.concatenate([jnp.stack([rounds, live]), summed])
+        return jnp.sqrt(dist), bid, counts
 
     pleaf = P(axis, None)
     out2 = P(None, None)
@@ -907,11 +932,11 @@ def build_sharded_search(mesh: Mesh, *, axis: str = "data", k: int = 1,
                          tune=None, config=None):
     """Builds a jitted sharded k-NN `search(idx, queries)` for the mesh.
 
-    The facade spelling over `build_sharded_plan`: the pure plan is traced
-    through one `jax.jit` and the historical k == 1 squeeze is applied
-    outside it, so results keep the `FreshIndex.search` shapes ((Q,) for
-    k == 1, (Q, k) ascending otherwise) while the compiled program is the
-    same one the serving layer AOT-compiles per batch bucket.
+    The free-function spelling over `build_sharded_plan`, behind the
+    deprecated `make_sharded_search`: the pure plan is traced through one
+    `jax.jit` (its counters dropped) and the historical k == 1 squeeze is
+    applied outside it, so results keep the `FreshIndex.search` shapes
+    ((Q,) for k == 1, (Q, k) ascending otherwise).
     """
     plan = jax.jit(build_sharded_plan(
         mesh, axis=axis, k=k, round_leaves=round_leaves,

@@ -1267,12 +1267,12 @@ class QueryEngine:
         d, i, counts = plan.run(snap, jnp.asarray(batch.queries))
         d = np.asarray(d)
         i = np.asarray(i)
-        # the local plans count (rounds, live query-rounds, refined
-        # pairs, kernel rows) over the bucket's rows, pad rows included;
-        # the sharded plan counts its rounds alone
-        counts = np.atleast_1d(np.asarray(counts))
+        # the plans count (rounds, live query-rounds, refined pairs,
+        # kernel rows, shard live rounds; `core.search.COUNTERS`) over
+        # the bucket's rows, pad rows included; a sharded plan sums the
+        # pairs over its shards
+        counts = np.asarray(counts)
         rounds = int(counts[0])
-        counted = counts.shape[0] > 1
         if snap.id_alias:
             # rows renamed by update() answer under their stable public
             # id; the remap uses the alias view frozen at this batch's
@@ -1305,15 +1305,14 @@ class QueryEngine:
             tstats = self._tier_note(batch.tier)
             tstats["queries"] += batch.n_real
             tstats["batches"] += 1
-            if counted:
-                self._live_query_rounds += int(counts[1])
-                self._refined_pairs += int(counts[2])
-                self._counted_rows += batch.queries.shape[0]
-                # leaves refined per query: the bucket's pairs over its
-                # rows (pad rows repeat the last real query)
-                tstats["visited_leaves"] += (int(counts[2]) * batch.n_real
-                                             / batch.queries.shape[0])
-                tstats["visited_n"] += batch.n_real
+            self._live_query_rounds += int(counts[1])
+            self._refined_pairs += int(counts[2])
+            self._counted_rows += batch.queries.shape[0]
+            # leaves refined per query: the bucket's pairs over its
+            # rows (pad rows repeat the last real query)
+            tstats["visited_leaves"] += (int(counts[2]) * batch.n_real
+                                         / batch.queries.shape[0])
+            tstats["visited_n"] += batch.n_real
             if early_stop:
                 tstats["early_stops"] += batch.n_real
             for fut, dst, src, n in batch.segments:

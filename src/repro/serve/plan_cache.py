@@ -99,7 +99,7 @@ def plan_key(k: int, knobs: Knobs) -> tuple:
 class CompiledPlan:
     """One AOT-compiled executable: fixed (bucket_Q, k, knobs, snapshot
     shape).  `run(snapshot, queries)` -> (dist (Q, k), ids (Q, k),
-    counts): the plan's (4,) counter array (`core.search.COUNTERS`).
+    counts): the plan's (5,) counter array (`core.search.COUNTERS`).
 
     `has_alive` mirrors the snapshot's tombstone state: epochs whose
     delta carries an alive mask compile (and run) the masked program —
@@ -137,8 +137,8 @@ class ShardedCompiledPlan:
     """One AOT-compiled MESH executable pair for a sharded snapshot.
 
     `core` is the compiled `build_sharded_plan` program (shard_map over
-    the mesh; returns (Q, k) dist/ids plus the replicated round count,
-    a scalar: the sharded plan counts nothing else);
+    the mesh; returns (Q, k) dist/ids plus the (5,) counter array,
+    `core.search.COUNTERS` counted over the shards);
     `merge` (present only for delta-carrying epochs) is the compiled
     `merge_delta_topk` that folds the exact scan of the snapshot's delta
     into the core answer — the SAME two-program split the sharded facade
@@ -166,14 +166,14 @@ class ShardedCompiledPlan:
 
     def run(self, snapshot, queries: jnp.ndarray):
         self.calls += 1
-        d, i, rounds = self._core(snapshot.core, queries)
+        d, i, counts = self._core(snapshot.core, queries)
         if self._merge is not None:
             if self.has_alive:
                 d, i = self._merge(snapshot.delta, queries, d, i,
                                    snapshot.delta_alive)
             else:
                 d, i = self._merge(snapshot.delta, queries, d, i)
-        return d, i, rounds
+        return d, i, counts
 
 
 class PlanCache:

@@ -1,6 +1,7 @@
 """The search plan's own counters (rounds, live query-rounds, refined
-(query, leaf) pairs, kernel rows) and the facade's record of them
-(`repro.obs`).
+(query, leaf) pairs, kernel rows, shard live rounds) and the facade's
+record of them (`repro.obs`).  The sharded plan's are checked on four
+devices in tests/test_mesh_build.py.
 
 The counts are checked against the same loop re-run on the host: the
 plan's lower bounds and priority queue, then one refine round at a time
@@ -107,6 +108,8 @@ def test_plan_counts_match_the_host_loop(small, backend, rule, delta):
     # six rows: one phase, every row in every round's refine call
     assert S.phase_widths(queries.shape[0]) == (queries.shape[0],)
     assert got[3] == queries.shape[0] * rounds
+    # one device refines in every round it runs
+    assert got[4] == rounds
 
     # the facade records the same counts, with a pending delta too
     if delta:
@@ -229,7 +232,8 @@ def test_phases_keep_every_answer_and_count(wide, backend, k):
     kw = dict(k=k, round_leaves=K, backend=backend)
     d, i, c = S.search_plan(idx, queries, **kw)
     d, i = np.asarray(d), np.asarray(i)
-    rounds, live, refined, kernel_rows = (int(v) for v in c)
+    rounds, live, refined, kernel_rows, shard_live = (int(v) for v in c)
+    assert shard_live == rounds
 
     trail = []
     want, d_host, i_host = host_loop(idx, queries, k=k, backend=backend,
@@ -252,7 +256,7 @@ def test_phases_keep_every_answer_and_count(wide, backend, k):
     np.testing.assert_array_equal(i, ib)
     np.testing.assert_allclose(d, db, rtol=1e-5, atol=1e-5)
 
-    # the facade records the four counts; `obs.counts` reads three
+    # the facade records the five counts; `obs.counts` reads three
     obs.clear()
     ix = FreshIndex.build(walks, IndexConfig(leaf_capacity=M,
                                              round_leaves=K,
@@ -261,4 +265,5 @@ def test_phases_keep_every_answer_and_count(wide, backend, k):
     rec = obs.records(last=1)[0]
     assert obs.counts(rec) == want
     assert obs.kernel_rows(rec) == kernel_rows
+    assert (obs.shard_live_rounds(rec), rec.shards) == (rounds, 1)
     obs.clear()

@@ -191,3 +191,117 @@ def test_search_plan_names_its_kernels(compile_tpu, spec, on_tpu,
     in_rounds = [ln for ln in text.splitlines() if norms in ln
                  and " reshape(" in ln and "while/body" in ln]
     assert not in_rounds, in_rounds
+
+
+# --------------------------------------------------------------------- #
+# four chips: the rw256-16m-4chip deployment, 2^22 series a chip
+# --------------------------------------------------------------------- #
+N_MESH = 4 * N_SERIES
+HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices), ("data",))
+
+
+@pytest.fixture
+def kernels_on_tpu(monkeypatch, on_tpu):
+    """The kernels' interpret default resolves from the CPU backend here:
+    steer every kernel the build and the plan call to Mosaic."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "resolve_interpret",
+                        lambda interpret=None: False)
+
+
+def _placed(mesh4, shape, dtype=jnp.float32):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    spec = P("data", None) if len(shape) == 2 else P("data")
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh4, spec))
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def test_mesh_build_compiles(mesh4, no_compile_cache, kernels_on_tpu):
+    """The build over series row-sharded over four v5e: the summarize
+    kernel runs per chip (an automatically partitioned Mosaic kernel is
+    refused), and a chip's series, index and temporaries fit its HBM."""
+    from repro.core.index import build_index_sharded
+    compiled = jax.jit(lambda x: build_index_sharded(
+        x, mesh4, "data", segments=W, leaf_capacity=M, backend="pallas")
+    ).lower(_placed(mesh4, (N_MESH, L))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES * 0.7
+
+
+def test_sharded_plan_names_its_kernels_and_collectives(
+        mesh4, no_compile_cache, kernels_on_tpu, monkeypatch):
+    """The sharded plan over 2^22 series a chip compiles for four v5e
+    with the Mosaic kernels; the benchmark's kernel and collective
+    patterns match what it holds, and the leaf norms are laid out once
+    before the rounds."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.index import FlatIndex
+    from repro.core.search import build_sharded_plan
+    from repro.kernels import ops
+    from repro.kernels.lb_distance import lb_distance
+    from repro.kernels.refine import refine_topk
+
+    monkeypatch.setattr(ops, "_refine_topk", refine_topk.__wrapped__)
+    monkeypatch.setattr(ops, "_lb_distance", lb_distance.__wrapped__)
+    nl = N_MESH // M
+
+    def s(shape, dtype=jnp.float32):
+        return _placed(mesh4, shape, dtype)
+    idx = FlatIndex(series=s((N_MESH, L)), paa=s((N_MESH, W)),
+                    words=s((N_MESH, W), jnp.uint8), sq_norms=s((N_MESH,)),
+                    perm=s((N_MESH,), jnp.int32),
+                    valid=s((N_MESH,), jnp.bool_),
+                    leaf_lo=s((nl, W)), leaf_hi=s((nl, W)),
+                    leaf_valid=s((nl,), jnp.bool_))
+    q = jax.ShapeDtypeStruct((128, L), jnp.float32,
+                             sharding=NamedSharding(mesh4, P()))
+    plan = build_sharded_plan(mesh4, k=10, round_leaves=K, backend="pallas")
+    compiled = jax.jit(plan).lower(idx, q).compile()
+    text = compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES * 0.4
+    kernels = [ln.strip().removeprefix("ROOT ")
+               for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for metric in ("refine_ms_per_query.batch",
+                   "lb_distance_roofline.batch"):
+        rx = re.compile(_metric_pattern(metric))
+        assert any(rx.search(n) for n in kernels), (metric, kernels)
+    # every collective the compiler kept is one the reader counts, and
+    # nothing else is but the scalar combiners inside the all-reduces
+    # (no operation of their own): the round's all-reduce-min and -max
+    # are there
+    rx = re.compile(_metric_pattern("collective_ms_per_query.batch"))
+    ops_of = re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+) ([a-z\-]+)\(",
+                        text, re.M)
+    collective = {n for n, _, op in ops_of
+                  if op.startswith(("all-reduce", "all-gather",
+                                    "reduce-scatter", "collective-permute",
+                                    "all-to-all"))}
+    counted = {n for n, _, _ in ops_of if rx.search(n)}
+    assert collective and collective <= counted, (collective, counted)
+    combiners = {n for n, ty, _ in ops_of
+                 if n in counted - collective and "[]" in ty}
+    assert counted - collective == combiners, counted - collective
+    kinds = {n.split(".")[0] for n in collective}
+    assert {"%pmin", "%pmax", "%all-gather"} <= kinds, kinds
+    # the leaf norms are laid out as the kernel reads them once, before
+    # the rounds: no round relayouts them
+    norms = f"f32[{nl // 4},1,{M}]"
+    in_rounds = [ln for ln in text.splitlines() if norms in ln
+                 and " reshape(" in ln and "while/body" in ln]
+    assert not in_rounds, in_rounds
