@@ -54,12 +54,12 @@ The four traverse-object stages map to:
                winners), with intra-round f32 sums equal to the last ulp.
                Rounds run in lockstep over the batch, and a query that has
                finished stays finished (its next lower bound only grows,
-               its k-th BSF only shrinks), so the loop runs in PHASES of
-               halving width (`phase_widths`): a phase refines only its
-               rows, and between phases the live rows are gathered to the
-               front of a batch of half the width.  A finished query's
-               refine steps then leave the kernel's grid, and no query's
-               rounds, pruning or answer change.
+               its k-th BSF only shrinks), so the loop (in both plans)
+               runs in PHASES of halving width (`phase_widths`): a phase
+               refines only its rows, and between phases the live rows
+               are gathered to the front of a batch of half the width.
+               A finished query's refine steps then leave the kernel's
+               grid, and no query's rounds, pruning or answer change.
 
 Expeditive vs standard (Section IV) on the mesh: in the sharded search each
 device refines against its LOCAL BSF (no communication = expeditive mode)
@@ -775,13 +775,28 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
     every device which queries are still live on some device, and the
     loop runs while any is, so every device executes the same rounds.
 
+    The rounds run in the phases of halving width that `phase_widths(Q)`
+    gives, as in `search_plan_impl`: a phase of B rows runs while more
+    of them are live on some device than the next width holds (the last
+    phase: while any is), all under the same cursor cap.  Between phases
+    the rows' buffers are written back to the (Q, k) buffers, and the
+    live rows are gathered, stably and with their queries, priority
+    queues, published bounds and liveness, to the front of the next,
+    narrower batch.  The order comes from the replicated liveness, so
+    every device applies the same row permutation and the per-row
+    all-reduces stay aligned.  A query dead on every device never comes
+    back and its round changes nothing, so each query's rounds, pruning
+    and answer are those of one batch of Q rows; at Q < 2 * PHASE_FLOOR
+    there is one phase.
+
     `counts` is the (5,) int32 `COUNTERS` array, replicated: the rounds
     of the collective loop; the (query, round) pairs in which the query
     was live on any device; and, summed over the devices, the (query,
-    leaf) pairs refined, the rows the refine calls ran with (every round
-    refines all Q rows on every device), and the rounds in which the
-    device refined at least one pair — a device whose queries are all
-    pruned locally still runs the round, at its lock-step cost.
+    leaf) pairs refined, the rows the refine calls ran with (Σ over
+    phases of B times the phase's rounds, on every device), and the
+    rounds in which the device refined at least one pair — a device
+    whose queries are all pruned locally still runs the round, at its
+    lock-step cost.
 
     The returned function is traceable but NOT jitted: `FreshIndex.search`
     and the serving layer (`serve.PlanCache`, AOT per (batch-bucket, k,
@@ -846,42 +861,81 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
             bound = jnp.minimum(pb, bsf_d[:, -1])
             return bound * inv_eps if stop_eps else bound
 
-        def live_anywhere(cursor, bsf_d, pb):
-            # (Q,) 1 where the query's next unrefined lower bound beats
+        def live_anywhere(cursor, sorted_lb, bsf_d, pb):
+            # (B,) 1 where the query's next unrefined lower bound beats
             # its bound on some device (the PQ is sorted: slot 0 decides)
             nxt = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
             live = (nxt[:, 0] < bound_of(bsf_d, pb)).astype(jnp.int32)
             return jax.lax.pmax(live, axis)
 
-        def cond(state):
-            cursor, live_q = state[0], state[5]
-            return jnp.logical_and(cursor < cap * K, jnp.any(live_q > 0))
+        def phase(q, q_sq, order, sorted_lb, state, keep: int):
+            """Collective rounds over the batch's rows while more than
+            `keep` of them are live on some device (keep == 0: while
+            any is); `live_q` is replicated, so every device stops at
+            the same round."""
+            def cond(state):
+                cursor, live_q = state[0], state[5]
+                live = (jnp.sum(live_q) > keep if keep
+                        else jnp.any(live_q > 0))
+                return jnp.logical_and(cursor < cap * K, live)
 
-        def body(state):
-            cursor, bsf_d, bsf_e, pb, rounds, live_q, counts = state
-            ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
-            lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K, axis=1)
-            alive = lbs < bound_of(bsf_d, pb)[:, None]
-            bsf_d, bsf_e = _refine_round(q, q_sq, series, norms, ids, alive,
-                                         bsf_d, bsf_e, M=M, k=k, backend=bk,
-                                         dma_depth=dd)
-            # standard mode: publish the global k-th bound every sync_every
-            do_sync = (rounds % sync_every) == (sync_every - 1)
-            gbsf = jax.lax.pmin(bsf_d[:, -1], axis)
-            pb = jnp.where(do_sync, jnp.minimum(pb, gbsf), pb)
-            # live query-rounds, refined pairs, rounds this device worked
-            n_alive = jnp.sum(alive, dtype=jnp.int32)
-            counts = counts + jnp.stack([jnp.sum(live_q), n_alive,
-                                         (n_alive > 0).astype(jnp.int32)])
-            return (cursor + K, bsf_d, bsf_e, pb, rounds + 1,
-                    live_anywhere(cursor + K, bsf_d, pb), counts)
+            def body(state):
+                cursor, bsf_d, bsf_e, pb, rounds, live_q, counts = state
+                ids = jax.lax.dynamic_slice_in_dim(order, cursor, K, axis=1)
+                lbs = jax.lax.dynamic_slice_in_dim(sorted_lb, cursor, K,
+                                                   axis=1)
+                alive = lbs < bound_of(bsf_d, pb)[:, None]
+                bsf_d, bsf_e = _refine_round(q, q_sq, series, norms, ids,
+                                             alive, bsf_d, bsf_e, M=M, k=k,
+                                             backend=bk, dma_depth=dd)
+                # standard mode: publish the global k-th bound every
+                # sync_every rounds
+                do_sync = (rounds % sync_every) == (sync_every - 1)
+                gbsf = jax.lax.pmin(bsf_d[:, -1], axis)
+                pb = jnp.where(do_sync, jnp.minimum(pb, gbsf), pb)
+                # live query-rounds, refined pairs, rounds this device
+                # worked
+                n_alive = jnp.sum(alive, dtype=jnp.int32)
+                counts = counts + jnp.stack([jnp.sum(live_q), n_alive,
+                                             (n_alive > 0).astype(jnp.int32)])
+                return (cursor + K, bsf_d, bsf_e, pb, rounds + 1,
+                        live_anywhere(cursor + K, sorted_lb, bsf_d, pb),
+                        counts)
 
+            return jax.lax.while_loop(cond, body, state)
+
+        # phases of halving width, as in search_plan_impl; the order of
+        # a narrower batch comes from the replicated `live_q`, so every
+        # device gathers the same rows and the per-row all-reduces stay
+        # aligned
+        widths = phase_widths(Q)
+        batch = (q, q_sq, order, sorted_lb)
         bsf_d, pb = jnp.full((Q, k), BIG), jnp.full((Q,), BIG)
         state = (jnp.int32(0), bsf_d, jnp.zeros((Q, k), jnp.int32), pb,
-                 jnp.int32(0), live_anywhere(0, bsf_d, pb),
+                 jnp.int32(0), live_anywhere(0, sorted_lb, bsf_d, pb),
                  jnp.zeros((3,), jnp.int32))
-        _, bsf_d, bsf_e, _, rounds, _, (live, refined, worked) = \
-            jax.lax.while_loop(cond, body, state)
+        kernel_rows = jnp.int32(0)
+        rows = None             # the batch's rows of the (Q, k) buffers
+        for B, nxt_w in zip(widths, widths[1:] + (0,)):
+            start = state[4]
+            state = phase(*batch, state, keep=nxt_w)
+            cursor, bsf_d, bsf_e, pb, rounds, live_q, counts = state
+            kernel_rows = kernel_rows + B * (rounds - start)
+            if rows is None:
+                out_d, out_e = bsf_d, bsf_e
+            else:
+                out_d = out_d.at[rows].set(bsf_d, unique_indices=True)
+                out_e = out_e.at[rows].set(bsf_e, unique_indices=True)
+            if nxt_w:
+                # live rows first (stable), dead ones after to fill the
+                # width
+                front = jnp.argsort(jnp.where(live_q > 0, 0, 1))[:nxt_w]
+                rows = front if rows is None else rows[front]
+                batch = tuple(a[front] for a in batch)
+                state = (cursor, bsf_d[front], bsf_e[front], pb[front],
+                         rounds, live_q[front], counts)
+        bsf_d, bsf_e = out_d, out_e
+        live, refined, worked = counts
 
         # recompute the local winners' distances in DIRECT form (matmul
         # form loses ~1e-3 absolute to f32 cancellation — see search())
@@ -899,7 +953,8 @@ def build_sharded_plan(mesh: Mesh, *, axis: str = "data", k: int = 1,
         bid = jnp.take_along_axis(all_i, pos, axis=1)
         # rounds and live are replicated (the loop and its liveness are
         # collective); the rest are this device's, summed over devices
-        summed = jax.lax.psum(jnp.stack([refined, Q * rounds, worked]), axis)
+        summed = jax.lax.psum(jnp.stack([refined, kernel_rows, worked]),
+                              axis)
         counts = jnp.concatenate([jnp.stack([rounds, live]), summed])
         return jnp.sqrt(dist), bid, counts
 

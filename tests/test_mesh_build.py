@@ -1,5 +1,5 @@
 """A build over series row-sharded over a mesh, and the sharded plan's
-counters, on four CPU devices.
+counters and phases, on four CPU devices.
 
 `FreshIndex.build` on an array whose rows are split over the mesh runs
 one program in which each device builds its own block; the index comes
@@ -16,10 +16,13 @@ import textwrap
 
 import pytest
 
+from plan_phases import phase_schedule
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHARDS = 4
 K = 4                    # leaves per round
-Q = 8                    # queries
+Q = 8                    # queries: one phase
+WIDE = 64                # queries of the phased batch
 
 SCRIPT = textwrap.dedent("""
     import json, os, sys, tempfile
@@ -43,7 +46,10 @@ SCRIPT = textwrap.dedent("""
 
     def host_loop(idx, qs, k, backend):
         # the sharded plan's rounds (sync_every 1) with every decision
-        # taken on the host, shard by shard: its five counters
+        # taken on the host, shard by shard, all rows every round: the
+        # counters (rounds, live, refined, shard live rounds) and the
+        # live queries at the start of each round
+        Q = qs.shape[0]
         parts = []
         for s in range(S):
             part = FlatIndex(*[jnp.asarray(np.asarray(a)[
@@ -65,9 +71,11 @@ SCRIPT = textwrap.dedent("""
             return np.any([p[4][:, cursor] < bound(p) for p in parts], 0)
 
         rounds = live = refined = worked = cursor = 0
+        trail = []
         while cursor < cap * K and live_anywhere(cursor).any():
             rounds += 1
-            live += int(live_anywhere(cursor).sum())
+            trail.append(int(live_anywhere(cursor).sum()))
+            live += trail[-1]
             for p in parts:
                 alive = p[4][:, cursor:cursor + K] < bound(p)[:, None]
                 refined += int(alive.sum())
@@ -80,7 +88,7 @@ SCRIPT = textwrap.dedent("""
             pb = np.minimum(pb, np.min([np.asarray(p[5])[:, -1]
                                         for p in parts], 0))
             cursor += K
-        return [rounds, live, refined, S * Q * rounds, worked]
+        return [rounds, live, refined, worked], trail
 
     def blocks(arr):
         # (first row, device) of each device's block, in row order
@@ -135,7 +143,8 @@ SCRIPT = textwrap.dedent("""
                            float(np.abs(np.asarray(d)
                                         - np.asarray(db)).max())]
         recs = obs.records()
-        r["host_counts"] = host_loop(ix.index, qs, 10, backend)
+        r["host_counts"], r["host_trail"] = host_loop(ix.index, qs, 10,
+                                                      backend)
         r["records"] = [[rec.queries, rec.round_leaves, rec.shards,
                          [int(v) for v in rec.counts.tolist()],
                          list(obs.counts(rec)), obs.kernel_rows(rec),
@@ -163,6 +172,39 @@ SCRIPT = textwrap.dedent("""
         d, i = ix.search(qs, k=10)
         db, ib = search_bruteforce(both, qs, k=10, alive=jnp.asarray(alive))
         r["add_delete"] = bool(np.array_equal(np.asarray(i), np.asarray(ib)))
+
+    # 64 queries whose rounds spread widely: near copies of indexed
+    # series finish in a few rounds, noisier ones run on, so the live
+    # queries fall through every phase width of a 64-row plan
+    walks = random_walk(S * 2048, L, seed=51)
+    wide = jnp.asarray(np.concatenate([
+        query_workload(walks, 16, noise_sigma=sigma, seed=52 + j)
+        for j, sigma in enumerate((0.05, 0.2, 0.5, 1.0))]))
+    for backend in ("ref", "pallas"):
+        r = out[backend]["wide"] = {}
+        ix = FreshIndex.build(jax.device_put(jnp.asarray(walks), rows_of),
+                              IndexConfig(leaf_capacity=M, round_leaves=K,
+                                          backend=backend))
+        for k in (1, 10):
+            obs.clear()
+            d, i = (np.asarray(a).reshape(len(wide), k)
+                    for a in ix.search(wide, k=k))
+            rec = obs.records(last=1)[0]
+            alone = [[np.asarray(a).reshape(k) for a in
+                      ix.search(wide[j:j + 1], k=k)]
+                     for j in range(len(wide))]
+            db, ib = (np.asarray(a).reshape(len(wide), k) for a in
+                      search_bruteforce(jnp.asarray(walks), wide, k=k))
+            host, trail = host_loop(ix.index, wide, k, backend)
+            r[k] = {"counts": [int(v) for v in rec.counts.tolist()],
+                    "kernel_rows": obs.kernel_rows(rec),
+                    "host_counts": host, "host_trail": trail,
+                    "alone": all(np.array_equal(dj, d[j])
+                                 and np.array_equal(ij, i[j])
+                                 for j, (dj, ij) in enumerate(alone)),
+                    "oracle": [bool(np.array_equal(i, ib)),
+                               float(np.abs(d - db).max())]}
+        obs.clear()
 
     # the spans around build and shard
     walks = random_walk(S * 64, L, seed=64)
@@ -239,7 +281,10 @@ def test_sharded_counters_keep_their_invariants(runs, backend):
         # refines its first leaf
         assert rounds <= live_q <= Q * rounds
         assert live_q <= refined <= rows * K
-        assert rows == SHARDS * Q * rounds
+        # every device refines every live row of each round, and at
+        # most the whole batch (a batch of Q < 16 rows runs one phase:
+        # the schedule's rows, checked against the host loop below)
+        assert SHARDS * live_q <= rows <= SHARDS * Q * rounds
         assert rounds <= shard_live <= SHARDS * rounds
 
 
@@ -248,7 +293,33 @@ def test_sharded_counters_match_the_host_loop(runs, backend):
     """The k = 10 search's counters are those of the same rounds taken
     with every pruning and stopping decision made on the host."""
     r = runs[backend]
-    assert r["records"][-1][3] == r["host_counts"]
+    rounds, live, refined, worked = r["host_counts"]
+    widths, rows = phase_schedule(r["host_trail"], Q)
+    assert widths == [Q]
+    assert r["records"][-1][3] == [rounds, live, refined,
+                                   SHARDS * sum(rows), worked]
+
+
+@pytest.mark.parametrize("k", [1, 10])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_sharded_phases_keep_every_answer_and_count(runs, backend, k):
+    """A 64-row batch on the mesh runs phases of 64, 32, 16 and 8 rows
+    on every device; each query's answer is the one it gets alone, bit
+    for bit, and the oracle's with global ids; the plan's rounds,
+    liveness and pruning are the host loop's, which refines all 64 rows
+    every round; only the refine calls' rows shrink."""
+    w = runs[backend]["wide"][str(k)]
+    rounds, live, refined, rows, shard_live = w["counts"]
+    assert [rounds, live, refined, shard_live] == w["host_counts"]
+    widths, sched = phase_schedule(w["host_trail"], WIDE)
+    assert widths == [64, 32, 16, 8]
+    assert set(sched) == set(widths)          # every phase ran a round
+    assert rows == w["kernel_rows"] == SHARDS * sum(sched)
+    assert SHARDS * live <= rows < SHARDS * WIDE * rounds
+    assert w["alone"] is True
+    ids_equal, dist_err = w["oracle"]
+    assert ids_equal
+    assert dist_err <= 1e-5
 
 
 @pytest.mark.parametrize("backend", BACKENDS)

@@ -21,6 +21,8 @@ from repro.api import FreshIndex, IndexConfig
 from repro.data.synthetic import query_workload, random_walk
 from repro.serve import EngineConfig
 
+from plan_phases import phase_schedule
+
 #: the module (`repro.core.search` names a function there)
 S = importlib.import_module("repro.core.search")
 
@@ -203,21 +205,6 @@ def wide():
     ix = FreshIndex.build(walks, IndexConfig(leaf_capacity=M,
                                              round_leaves=K))
     return walks, jnp.asarray(queries), ix.index
-
-
-def phase_schedule(trail, Q):
-    """The rows each round of a phased plan refines, from the live
-    queries at the start of each round: a phase of B rows runs while
-    more than the next phase's rows are live."""
-    widths = [Q]
-    while widths[-1] // 2 >= 8:
-        widths.append(widths[-1] // 2)
-    p, rows = 0, []
-    for n in trail:
-        while p + 1 < len(widths) and n <= widths[p + 1]:
-            p += 1
-        rows.append(widths[p])
-    return widths, rows
 
 
 @pytest.mark.parametrize("k", [1, 10])

@@ -246,7 +246,9 @@ def test_sharded_plan_names_its_kernels_and_collectives(
     """The sharded plan over 2^22 series a chip compiles for four v5e
     with the Mosaic kernels; the benchmark's kernel and collective
     patterns match what it holds, and the leaf norms are laid out once
-    before the rounds."""
+    before the rounds.  The 128-row plan refines in phases of 128, 64,
+    32, 16 and 8 rows: one refine call each, and in each phase's loop
+    the round's all-reduce-min and -max over that many rows."""
     import re
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -281,6 +283,13 @@ def test_sharded_plan_names_its_kernels_and_collectives(
                    "lb_distance_roofline.batch"):
         rx = re.compile(_metric_pattern(metric))
         assert any(rx.search(n) for n in kernels), (metric, kernels)
+    rx = re.compile(_metric_pattern("refine_ms_per_query.batch"))
+    refine = [n for n in kernels if rx.search(n)]
+    assert len(kernels) == len(refine) + 1, kernels     # and lb_distance
+    # a refine call's first output is the (rows, 1, lanes) distance buffer
+    widths = [int(re.search(r"= \(f32\[(\d+),1,", n).group(1))
+              for n in refine]
+    assert sorted(widths) == [8, 16, 32, 64, 128], widths
     # every collective the compiler kept is one the reader counts, and
     # nothing else is but the scalar combiners inside the all-reduces
     # (no operation of their own): the round's all-reduce-min and -max
@@ -299,6 +308,13 @@ def test_sharded_plan_names_its_kernels_and_collectives(
     assert counted - collective == combiners, counted - collective
     kinds = {n.split(".")[0] for n in collective}
     assert {"%pmin", "%pmax", "%all-gather"} <= kinds, kinds
+    # a phase's round all-reduces a vector of its rows
+    rows_of = {kind: {int(m) for n, ty, _ in ops_of
+                      if n.startswith(kind + ".")
+                      for m in re.findall(r"^[fs]32\[(\d+)\]", ty)}
+               for kind in ("%pmin", "%pmax")}
+    assert rows_of["%pmin"] == {8, 16, 32, 64, 128}, rows_of
+    assert rows_of["%pmax"] == {8, 16, 32, 64, 128}, rows_of
     # the leaf norms are laid out as the kernel reads them once, before
     # the rounds: no round relayouts them
     norms = f"f32[{nl // 4},1,{M}]"
